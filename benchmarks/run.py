@@ -102,6 +102,9 @@ def main() -> None:
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
     only = [s for s in args.only.split(",") if s]
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     results, failures = {}, 0
     for mod_name, title in MODULES:

@@ -9,6 +9,7 @@ latency trade-off and the footprint-vs-static-buffer comparison.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.serving.control_plane import EnergyFirstControlPlane
 from repro.telemetry.simulator import SimulatorConfig
 from repro.workload.azure import WorkloadConfig, generate_trace
@@ -16,6 +17,7 @@ from repro.workload.functions import paper_functions
 
 
 def main():
+    enable_compile_cache()
     reg = paper_functions()
     trace = generate_trace(
         reg, WorkloadConfig(duration_s=240.0, load=1.2, seed=6, arrival="bursty")

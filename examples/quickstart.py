@@ -10,6 +10,7 @@ marginal-energy ground truth (paper Eq. 6).
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.metrics import cosine_similarity
 from repro.serving.control_plane import EnergyFirstControlPlane
 from repro.telemetry.simulator import SimulatorConfig
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 
 
 def main():
+    enable_compile_cache()
     registry = paper_functions()
     trace = generate_trace(registry, WorkloadConfig(duration_s=300.0, load=1.0, seed=0))
     print(f"trace: {trace.num_invocations} invocations of {trace.num_fns} functions over {trace.duration:.0f}s")

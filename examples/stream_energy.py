@@ -18,6 +18,7 @@ energy-first control plane does *during* the segment, not after it:
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.capping import CappingConfig, PowerCapController
 from repro.core.pricing import energy_price_usd
 from repro.serving.control_plane import StreamingFootprintTracker
@@ -33,6 +34,7 @@ CAP_WATTS = 460.0  # fleet-level software cap (2 nodes, ~95 W idle each)
 
 
 def main():
+    enable_compile_cache()
     registry = paper_functions()
     traces = [
         generate_trace(registry, WorkloadConfig(duration_s=DURATION, load=1.2, seed=s))

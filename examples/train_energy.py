@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config
 from repro.configs.shapes import ShapeConfig
 from repro.core.pricing import PricingConfig, carbon_footprint_g, energy_price_usd
@@ -28,6 +29,7 @@ CHIP_IDLE_W, CHIP_DYN_W, MFU_GUESS = 60.0, 160.0, 0.35
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("xlstm-350m", reduced=True)
     api = build(cfg)
     shape = ShapeConfig("t", 128, 8, "train")

@@ -43,6 +43,8 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core.disaggregation import MATMUL_PRECISION
+
 Array = jax.Array
 
 
@@ -109,8 +111,10 @@ def _fit_ridge_one(features: Array, power: Array, lam, mask=None) -> LinearPower
     # that keeps the gram invertible when every sample is masked out (the
     # unmasked path stays bit-identical to the pre-mask solve).
     reg = reg.at[f, f].set(0.0 if mask is None else 1e-9)
+    xm = (xb * m[:, None]).T
     theta = jnp.linalg.solve(
-        (xb * m[:, None]).T @ xb + reg, (xb * m[:, None]).T @ power
+        jnp.matmul(xm, xb, precision=MATMUL_PRECISION) + reg,
+        jnp.matmul(xm, power, precision=MATMUL_PRECISION),
     )
     w = theta[:f] / x_std
     b = theta[f] - jnp.sum(theta[:f] * x_mean / x_std)
@@ -167,7 +171,7 @@ def _fit_svr_one(features: Array, power: Array, lam, epsilon, lr, iters) -> Line
 
     def loss(params):
         w, b = params
-        resid = xs @ w + b - power
+        resid = jnp.matmul(xs, w, precision=MATMUL_PRECISION) + b - power
         hinge = jnp.maximum(jnp.abs(resid) - epsilon, 0.0)
         return jnp.mean(hinge) + 0.5 * lam * jnp.sum(w * w)
 
@@ -221,8 +225,8 @@ def _dynamic_power(model: LinearPowerModel, features: Array) -> Array:
     each node's features against that node's own weight row."""
     w = model.weights
     if w.ndim == 1:
-        return features @ w
-    return jnp.einsum("b...f,bf->b...", features, w)
+        return jnp.matmul(features, w, precision=MATMUL_PRECISION)
+    return jnp.einsum("b...f,bf->b...", features, w, precision=MATMUL_PRECISION)
 
 
 def _bias_like(model: LinearPowerModel, out_ndim: int) -> Array:

@@ -37,6 +37,13 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+#: Precision of every f32 contraction in the energy math (grams, right-hand
+#: sides, innovations, NNLS matvecs, counter features and models, the
+#: reconstruction).  A TPU's default takes one bf16 pass, which the
+#: whole-trace X_0 solve amplifies far past float32 noise; HIGHEST keeps the
+#: chip within the engine's CPU and oracle pins.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class DisaggregationConfig:
@@ -62,8 +69,8 @@ def solve_ridge(c: Array, w: Array, lam: float = 1e-3, *, nonneg: bool = True) -
       (M,) per-function power estimate in watts.
     """
     m = c.shape[1]
-    gram = c.T @ c + lam * jnp.eye(m, dtype=c.dtype)
-    rhs = c.T @ w
+    gram = jnp.matmul(c.T, c, precision=MATMUL_PRECISION) + lam * jnp.eye(m, dtype=c.dtype)
+    rhs = jnp.matmul(c.T, w, precision=MATMUL_PRECISION)
     # Normal equations via Cholesky: gram is SPD by construction.
     chol = jnp.linalg.cholesky(gram)
     x = jax.scipy.linalg.cho_solve((chol, True), rhs)
@@ -85,7 +92,7 @@ def solve_nnls_gram(gram: Array, rhs: Array, *, iters: int = 200) -> Array:
 
     def body(i, carry):
         x, y, t = carry
-        grad = jnp.einsum("...ij,...j->...i", gram, y) - rhs
+        grad = jnp.einsum("...ij,...j->...i", gram, y, precision=MATMUL_PRECISION) - rhs
         x_new = jnp.maximum(y - step * grad, 0.0)
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         y_new = x_new + ((t - 1.0) / t_new) * (x_new - x)
@@ -103,8 +110,10 @@ def solve_nnls(c: Array, w: Array, lam: float = 1e-3, *, iters: int = 200) -> Ar
     min_{X >= 0} 0.5||C X - W||^2 + 0.5 lam ||X||^2, with Lipschitz step
     1/L, L = ||C^T C||_2 + lam bounded by its trace (cheap, safe).
     """
-    gram = c.T @ c + lam * jnp.eye(c.shape[1], dtype=c.dtype)
-    rhs = c.T @ w
+    gram = jnp.matmul(c.T, c, precision=MATMUL_PRECISION) + lam * jnp.eye(
+        c.shape[1], dtype=c.dtype
+    )
+    rhs = jnp.matmul(c.T, w, precision=MATMUL_PRECISION)
     return solve_nnls_gram(gram, rhs, iters=iters)
 
 

@@ -19,6 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.disaggregation import MATMUL_PRECISION
 from repro.core.engine.types import Array, EngineConfig
 from repro.core.kalman import KalmanState, kalman_init
 
@@ -51,7 +52,10 @@ def _node_init_gram(c_node: Array, w_node: Array) -> tuple[Array, Array]:
     engine and the sequential oracle see bitwise-equal grams.
     """
     cf = c_node.reshape(-1, c_node.shape[-1])
-    return cf.T @ cf, cf.T @ w_node.reshape(-1)
+    return (
+        jnp.matmul(cf.T, cf, precision=MATMUL_PRECISION),
+        jnp.matmul(cf.T, w_node.reshape(-1), precision=MATMUL_PRECISION),
+    )
 
 
 def fleet_initial_estimate(

@@ -45,8 +45,6 @@ def _sharded_segment_runner(fn, config: EngineConfig, with_ticks: bool, mesh, de
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     node = P(mesh.axis)
 
     if default_init:
@@ -61,7 +59,7 @@ def _sharded_segment_runner(fn, config: EngineConfig, with_ticks: bool, mesh, de
         in_specs = (node, node, node)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh.mesh,
             in_specs=in_specs,
@@ -102,8 +100,6 @@ def _sharded_step_runner(step_impl, config: EngineConfig, mesh, has_valid: bool)
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     node, rep = P(mesh.axis), P()
     state_specs = FleetStreamState(
         kalman=node, c_buf=node, w_buf=node, a=node,
@@ -116,7 +112,7 @@ def _sharded_step_runner(step_impl, config: EngineConfig, mesh, has_valid: bool)
     att_specs = TickAttribution(
         tick_power=node, unattributed=node, x=node, step_completed=rep
     )
-    return shard_map(
+    return jax.shard_map(
         functools.partial(step_impl, config=config),
         mesh=mesh.mesh,
         in_specs=(state_specs, step_specs),
@@ -136,14 +132,12 @@ def _sharded_reset_runner(reset_local, mesh):
     sharded stream without any collective."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     node, rep = P(mesh.axis), P()
     state_specs = FleetStreamState(
         kalman=node, c_buf=node, w_buf=node, a=node,
         lat_sum=node, lat_sumsq=node, tick_in_step=rep, step_idx=rep,
     )
-    return shard_map(
+    return jax.shard_map(
         reset_local,
         mesh=mesh.mesh,
         in_specs=(state_specs, node, node),

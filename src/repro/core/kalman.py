@@ -33,7 +33,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.disaggregation import solve_nnls, solve_nnls_gram
+from repro.core.disaggregation import MATMUL_PRECISION, solve_nnls, solve_nnls_gram
 
 Array = jax.Array
 
@@ -166,7 +166,7 @@ def kalman_step(
     u = solve_nnls(c_step, w_step, config.ridge_lambda, iters=config.nnls_iters)
 
     # Innovation: mean residual of the previous estimate on new measurements.
-    resid = w_step - c_step @ state.x
+    resid = w_step - jnp.matmul(c_step, state.x, precision=MATMUL_PRECISION)
     window_active = jnp.sum(c_step, axis=1) > 0
     z = jnp.sum(resid * window_active) / jnp.maximum(jnp.sum(window_active), 1.0)
 
@@ -258,8 +258,12 @@ def precompute_step_inputs(
     """
     m = c_steps.shape[-1]
     if gram_fn is None:
-        gram = jnp.einsum("...nm,...nk->...mk", c_steps, c_steps)
-        rhs = jnp.einsum("...nm,...n->...m", c_steps, w_steps)
+        gram = jnp.einsum(
+            "...nm,...nk->...mk", c_steps, c_steps, precision=MATMUL_PRECISION
+        )
+        rhs = jnp.einsum(
+            "...nm,...n->...m", c_steps, w_steps, precision=MATMUL_PRECISION
+        )
     else:
         lead = c_steps.shape[:-2]
         gram, rhs = gram_fn(
@@ -274,7 +278,7 @@ def precompute_step_inputs(
         gram=gram,
         rhs=rhs,
         s_w=jnp.sum(w_steps * wa, axis=-1),
-        s_c=jnp.einsum("...nm,...n->...m", c_steps, wa),
+        s_c=jnp.einsum("...nm,...n->...m", c_steps, wa, precision=MATMUL_PRECISION),
         n_act=jnp.sum(wa, axis=-1),
         a=a_steps,
         lat_sum=lat_sums,
@@ -293,7 +297,9 @@ def kalman_step_gram(
 
     # Innovation from the hoisted linear statistics:
     # sum_w (W - C X) * active = s_w - s_c . X.
-    z = (inp.s_w - jnp.dot(inp.s_c, state.x)) / jnp.maximum(inp.n_act, 1.0)
+    z = (
+        inp.s_w - jnp.dot(inp.s_c, state.x, precision=MATMUL_PRECISION)
+    ) / jnp.maximum(inp.n_act, 1.0)
 
     return _apply_update(state, u, z, inp.a, inp.lat_sum, inp.lat_sumsq, config)
 

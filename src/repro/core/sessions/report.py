@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.disaggregation import MATMUL_PRECISION
 from repro.core.footprints import FootprintSpectrum, assemble_spectrum
 from repro.core.metrics import total_power_error
 
@@ -90,12 +91,14 @@ def _finalize_report(
         x_fns, mean_lat, counts, jnp.asarray(cp_energy), jnp.asarray(idle_energy)
     )
 
-    w_hat_init = c_aug[:init_n] @ x0 + (
+    w_hat_init = jnp.matmul(c_aug[:init_n], x0, precision=MATMUL_PRECISION) + (
         offset[:init_n] if hasattr(offset, "shape") else offset
     )
     parts = [w_hat_init]
     if s > 0:
-        per_step = jnp.einsum("snm,sm->sn", c_steps, traj).reshape(-1)
+        per_step = jnp.einsum(
+            "snm,sm->sn", c_steps, traj, precision=MATMUL_PRECISION
+        ).reshape(-1)
         off_steps = (
             offset[init_n : init_n + s * step_windows]
             if hasattr(offset, "shape")

@@ -474,8 +474,6 @@ def _totals_runner(mesh: FleetMesh, has_mask: bool, has_chip: bool):
     input, the combined variant the (B, M) chip split — each sharded
     along the node axis like every other per-node array; the plain dense
     variant keeps the original three-input plain-sum program."""
-    from repro.distributed.compat import shard_map
-
     node = P(mesh.axis)
 
     def _psum(part: FleetTotals) -> FleetTotals:
@@ -493,7 +491,7 @@ def _totals_runner(mesh: FleetMesh, has_mask: bool, has_chip: bool):
     in_specs = (node, node, node) + (node,) * (int(has_mask) + int(has_chip))
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             _local_psum,
             mesh=mesh.mesh,
             in_specs=in_specs,

@@ -4,15 +4,19 @@ batched normal-equation assembly for the disaggregation solve (Eq. 1).
 The paper solves ``min_X ||C X - W||`` per server with scipy on the host.
 A fleet controller solves it for (nodes x Kalman-windows) batches each
 step.  TPU-native rethink: assemble ``G = C^T C`` (M x M) and ``r = C^T W``
-(M) for the whole batch in one MXU-tiled pass — the window dimension N
-(thousands) is the contraction dim, streamed through VMEM in ``n_block``
-tiles and accumulated in an f32 VMEM scratch; M (functions per node, 64-256)
-is MXU-aligned by padding.  The small SPD solves then run as a batched
-Cholesky on the assembled grams (they are O(M^3) with tiny constants — the
-bandwidth-heavy part is this assembly, which is what the kernel owns).
+(M) for the whole batch in one MXU-tiled pass.  ``W`` rides as one more
+column of ``C``, so a single gram ``[C | W]^T [C | W]`` holds both: ``G`` is
+its leading M x M block and ``r`` the first M entries of its last column.
+The window dimension N (thousands) is the contraction dim, streamed through
+VMEM in ``n_block`` tiles and accumulated in the resident f32 output block;
+M + 1 is padded to the 128-lane MXU width.  The small SPD solves then run
+as a batched Cholesky or FISTA on the assembled grams (they are O(M^3)
+with tiny constants — the bandwidth-heavy part is this assembly, which is
+what the kernel owns).
 
 Grid: (batch, n_blocks); n_blocks is the sequential axis carrying the
-accumulator.  Validated against ``ref.disagg_gram`` in interpret mode.
+accumulator.  Validated against ``ref.disagg_gram`` in interpret mode and
+compiled for v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -24,39 +28,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.core.disaggregation import MATMUL_PRECISION, solve_nnls_gram
 
 
-def _gram_kernel(c_ref, w_ref, g_ref, r_ref, acc_g, acc_r, *, nn: int):
-    ni = pl.program_id(1)
-
-    @pl.when(ni == 0)
+def _gram_kernel(x_ref, g_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_g[...] = jnp.zeros_like(acc_g)
-        acc_r[...] = jnp.zeros_like(acc_r)
+        g_ref[...] = jnp.zeros_like(g_ref)
 
-    c = c_ref[0].astype(jnp.float32)                        # (nb, M)
-    w = w_ref[...].astype(jnp.float32)                      # (1, nb)
-    acc_g[...] += jax.lax.dot_general(
-        c, c, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    x = x_ref[0].astype(jnp.float32)                        # (nb, K)
+    g_ref[0] += jax.lax.dot_general(
+        x, x, (((0,), (0,)), ((), ())),
+        precision=MATMUL_PRECISION, preferred_element_type=jnp.float32,
     )
-    acc_r[...] += jax.lax.dot_general(
-        w, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(ni == nn - 1)
-    def _finalize():
-        g_ref[0] = acc_g[...].astype(g_ref.dtype)
-        r_ref[0] = acc_r[...].astype(r_ref.dtype)
-
-
-def _pad_axis(x, axis, mult):
-    rem = (-x.shape[axis]) % mult
-    if rem == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad)
 
 
 @functools.partial(jax.jit, static_argnames=("n_block", "interpret"))
@@ -72,41 +56,28 @@ def disagg_gram(
     if c.ndim == 2:
         c, w, squeeze = c[None], w[None], True
     g_b, n, m = c.shape
+    # A block of N must be a multiple of 8 rows or the whole (padded) axis.
     n_block = min(n_block, max(n, 8))
-    # Pad M to the 128-lane MXU width and N to the block size; zero padding
-    # contributes nothing to either product.
-    m_pad = max(((m + 127) // 128) * 128, 128)
-    cp = jnp.pad(c, [(0, 0), (0, (-n) % n_block), (0, m_pad - m)])
-    wp = _pad_axis(w, 1, n_block)
-    nn = cp.shape[1] // n_block
+    # Pad M + 1 to the 128-lane MXU width and N to the block size; zero
+    # padding contributes nothing to the gram.
+    k = ((m + 1 + 127) // 128) * 128
+    x = jnp.concatenate([c, w[..., None].astype(c.dtype)], axis=-1)
+    x = jnp.pad(x, [(0, 0), (0, (-n) % n_block), (0, k - m - 1)])
+    nn = x.shape[1] // n_block
 
-    kernel = functools.partial(_gram_kernel, nn=nn)
-    gram, rhs = pl.pallas_call(
-        kernel,
+    gram = pl.pallas_call(
+        _gram_kernel,
         grid=(g_b, nn),
-        in_specs=[
-            pl.BlockSpec((1, n_block, m_pad), lambda b, ni: (b, ni, 0)),
-            pl.BlockSpec((1, n_block), lambda b, ni: (b, ni)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m_pad, m_pad), lambda b, ni: (b, 0, 0)),
-            pl.BlockSpec((1, 1, m_pad), lambda b, ni: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g_b, m_pad, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((g_b, 1, m_pad), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((m_pad, m_pad), jnp.float32),
-            pltpu.VMEM((1, m_pad), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        in_specs=[pl.BlockSpec((1, n_block, k), lambda b, ni: (b, ni, 0))],
+        out_specs=pl.BlockSpec((1, k, k), lambda b, ni: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g_b, k, k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(cp, wp)
+    )(x)
+    rhs = gram[:, :m, m]
     gram = gram[:, :m, :m]
-    rhs = rhs[:, 0, :m]
     if squeeze:
         return gram[0], rhs[0]
     return gram, rhs
@@ -130,8 +101,6 @@ def disagg_solve_nnls(
     (G, M) non-negative power estimates out, with the window dimension
     touched exactly once (inside the kernel).
     """
-    from repro.core.disaggregation import solve_nnls_gram
-
     gram, rhs = disagg_gram(c, w, interpret=interpret)
     m = gram.shape[-1]
     gram = gram + lam * jnp.eye(m, dtype=gram.dtype)

@@ -15,6 +15,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config
 from repro.configs.shapes import ShapeConfig
 from repro.core.profiler import FaasMeterProfiler, ProfilerConfig
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 
 def main() -> None:
     """CLI: continuous-batching serving smoke across model-zoo architectures."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--archs", default="internlm2-1.8b,xlstm-350m,olmoe-1b-7b")
     ap.add_argument("--requests", type=int, default=30)

@@ -25,10 +25,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.distributed.compat import shard_map
 from repro.distributed import sharding as shd
 from repro.distributed.sharding import shard_activation
 from repro.models.common import Param

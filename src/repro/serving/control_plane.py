@@ -53,7 +53,7 @@ from repro.telemetry.simulator import (
     SimulatorConfig,
 )
 from repro.workload.functions import FunctionRegistry
-from repro.workload.trace import InvocationTrace
+from repro.workload.trace import InvocationTrace, pad_trace
 
 import jax.numpy as jnp
 
@@ -750,9 +750,14 @@ class EnergyFirstControlPlane:
         ragged = len(set(durations)) > 1
         duration = durations if ragged else durations[0]
         num_fns = traces[0].num_fns
+        # One common trace length (fn_id = -1 padding contributes nothing):
+        # the per-node bootstrap ops then compile once for the fleet, not
+        # once per distinct invocation count, which on a TPU cost most of
+        # a 64-node profile's wall time.
+        k = max(max(t.fn_id.shape[0] for t in traces), 1)
         trace_arrays = [
-            (jnp.asarray(t.fn_id), jnp.asarray(t.start), jnp.asarray(t.end))
-            for t in traces
+            (jnp.asarray(p.fn_id), jnp.asarray(p.start), jnp.asarray(p.end))
+            for p in (pad_trace(t, k) for t in traces)
         ]
         tels = [s.telemetry for s in sims]
         has_chip = [tel.chip_power is not None for tel in tels]

@@ -21,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.disaggregation import MATMUL_PRECISION
+
 Array = jax.Array
 
 NUM_FEATURES = 3
@@ -55,8 +57,8 @@ def window_counters(
     hbm_rate = jnp.asarray(hbm_gb, jnp.float32) / lat
     feats = jnp.stack(
         [
-            c @ gflop_rate,              # GFLOPs in window
-            c @ hbm_rate,                # HBM GB in window
+            jnp.matmul(c, gflop_rate, precision=MATMUL_PRECISION),  # GFLOPs in window
+            jnp.matmul(c, hbm_rate, precision=MATMUL_PRECISION),    # HBM GB in window
             jnp.sum(c, axis=-1),         # busy seconds in window
         ],
         axis=-1,

@@ -27,18 +27,6 @@ from typing import Sequence
 import numpy as np
 
 
-def trapezoid(y: np.ndarray, x: np.ndarray) -> np.ndarray:
-    """numpy-version-portable trapezoidal integration.
-
-    ``np.trapezoid`` only exists on numpy >= 2.0 (where ``np.trapz`` was
-    removed); older numpys have only ``np.trapz``.  Resolved at call time so
-    the fallback is testable by masking the attribute."""
-    fn = getattr(np, "trapezoid", None)
-    if fn is None:  # numpy < 2.0
-        fn = np.trapz
-    return fn(y, x)
-
-
 @dataclasses.dataclass(frozen=True)
 class SensorConfig:
     rate_hz: float
@@ -70,7 +58,7 @@ class PowerSignal:
     def energy_j(self) -> float:
         """Trapezoidal integral — what 'total energy from coarse measurements'
         means for the marginal-energy protocol (Eq. 6)."""
-        return float(trapezoid(self.watts, self.times))
+        return float(np.trapezoid(self.watts, self.times))
 
 
 def sense(

@@ -199,3 +199,52 @@ class TestCosts:
         # decode arithmetic intensity << machine balance: bytes dominate
         intensity = c.flops / c.hbm_bytes
         assert intensity < 240  # v5e balance ~ 197e12/819e9 ~ 240
+
+
+class TestCompileCache:
+    """``repro.compile_cache``: where the entry points keep compiled code."""
+
+    def test_env_dir_receives_the_cache(self, tmp_path):
+        import os
+        import pathlib
+        import subprocess
+        import sys
+
+        src = pathlib.Path(__file__).resolve().parents[1] / "src"
+        env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+               "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(src)}
+        code = (
+            "from repro.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.block_until_ready(jax.jit(lambda x: x * 2.0 + 1.0)(jnp.ones(3)))\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, capture_output=True,
+            text=True, timeout=120, check=True,
+        )
+        assert out.stdout.strip() == str(tmp_path)
+        assert any(p.name.endswith("-cache") for p in tmp_path.iterdir())
+
+    def test_default_dir_is_fixed_and_ignored(self, monkeypatch):
+        import pathlib
+
+        import jax
+
+        from repro.compile_cache import enable_compile_cache
+
+        root = pathlib.Path(__file__).resolve().parents[1]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        saved = (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+        )
+        try:
+            got = enable_compile_cache()
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        finally:
+            jax.config.update("jax_compilation_cache_dir", saved[0])
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
+        assert ".jax_cache/" in (root / ".gitignore").read_text().splitlines()

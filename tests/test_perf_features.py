@@ -104,12 +104,11 @@ class TestChunkedCE:
 
 class TestZero3Rules:
     def test_batch_takes_both_axes(self):
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AbstractMesh, PartitionSpec as P
 
-        from repro.distributed.compat import abstract_mesh
         from repro.distributed.sharding import ZERO3_RULES, spec_for
 
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         assert spec_for(("batch", None), (256, 128), mesh, ZERO3_RULES) == P(("data", "model"))
         # TP axes replicate
         assert spec_for(("embed", "qkv"), (4096, 4096), mesh, ZERO3_RULES) == P(("data", "model"))
@@ -119,12 +118,11 @@ class TestZero3Rules:
         assert spec_for(("embed", "lm_head"), (4096, 50176), mesh, ZERO3_RULES) == P(None, ("data", "model"))
 
     def test_ep_rules_reserve_model_for_experts(self):
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AbstractMesh, PartitionSpec as P
 
-        from repro.distributed.compat import abstract_mesh
         from repro.distributed.sharding import EP_RULES, spec_for
 
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         assert spec_for(("expert", "embed", "expert_mlp"), (64, 2048, 1408), mesh, EP_RULES) == P("model", "data")
         assert spec_for(("embed", "qkv"), (2048, 2048), mesh, EP_RULES) == P("data")
 

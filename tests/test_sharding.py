@@ -6,16 +6,15 @@ which is all ``spec_for`` consults.
 """
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.distributed.compat import abstract_mesh
 from repro.distributed.sharding import SERVE_RULES, TRAIN_RULES, spec_for
 
 
 def _mesh(multi_pod=False):
     if multi_pod:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_batch_falls_back_without_pod():

@@ -379,6 +379,26 @@ def test_profile_fleet_short_segment_has_no_tracker():
     assert len(out) == 1 and out[0].footprint_stream is None
 
 
+def test_profile_fleet_compiles_bootstrap_once_per_fleet():
+    """Nodes whose traces differ in length share one compile of each
+    per-node bootstrap program: the traces are padded to one length."""
+    from repro.core.contribution import contribution_matrix
+    from repro.serving.control_plane import EnergyFirstControlPlane
+    from repro.workload.azure import WorkloadConfig, generate_trace
+    from repro.workload.functions import paper_functions
+
+    reg = paper_functions()
+    cp = EnergyFirstControlPlane(reg)
+    traces = [
+        generate_trace(reg, WorkloadConfig(duration_s=150.0, load=load, seed=s))
+        for s, load in ((41, 0.7), (42, 1.0), (43, 1.3))
+    ]
+    assert len({t.fn_id.shape[0] for t in traces}) == 3
+    before = contribution_matrix._cache_size()
+    cp.profile_fleet(traces, seeds=[1, 2, 3], mesh=None)
+    assert contribution_matrix._cache_size() <= before + 1
+
+
 def test_pack_fleet_inputs_pads_and_masks_without_warning():
     """The old ragged-tail UserWarning + truncation is gone: packing is
     pad-and-mask by default (warning-free), with ``lengths`` driving the

@@ -116,17 +116,6 @@ def test_window_edge_sample_goes_to_next_window():
     np.testing.assert_array_equal(got, w)
 
 
-def test_energy_j_trapezoid_fallback(monkeypatch):
-    # numpy < 2 has no np.trapezoid; the shim must fall back to np.trapz.
-    sig = src.PowerSignal(
-        times=np.array([0.0, 1.0, 2.0]), watts=np.array([1.0, 3.0, 5.0]), rate_hz=1.0
-    )
-    want = sig.energy_j()
-    monkeypatch.delattr(np, "trapezoid")
-    assert not hasattr(np, "trapezoid")
-    assert sig.energy_j() == want == 6.0
-
-
 # ---------------------------------------------------------------------------
 # Fleet-batched chain: bitwise pins against the per-node loop.
 # ---------------------------------------------------------------------------
