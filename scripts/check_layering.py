@@ -10,7 +10,8 @@ import whose target sits on a HIGHER layer than the importing module.
 
 Layers (lower may never import higher):
 
-    0  repro.kernels.*, repro.core.disaggregation   pure math, no deps up
+    0  repro.kernels.*, repro.core.disaggregation,  pure math and host
+       repro.tracing                                spans, no deps up
     1  repro.core.engine.*, repro.distributed.*,    jitted stage pipeline +
        core estimator peers (kalman, contribution,  the math it composes
        cpu_model, sync, metrics, footprints,
@@ -39,6 +40,7 @@ SRC = pathlib.Path(__file__).resolve().parent.parent / "src"
 # Longest-prefix match decides a module's layer; None = unconstrained.
 LAYERS: dict[str, int] = {
     "repro.kernels": 0,
+    "repro.tracing": 0,  # host spans; imports only jax and numpy
     "repro.core.disaggregation": 0,  # pure-math leaf; the Pallas solver's fallback
     "repro.core.engine": 1,
     "repro.distributed": 1,

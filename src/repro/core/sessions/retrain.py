@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import cpu_model as cpumod
 from repro.core import sync as syncmod
 from repro.core.sessions.combined import combined_chip_power
@@ -43,13 +44,14 @@ class RetrainMixin:
             np.arange(lo, hi)[None, :] < self._n_nodes[:, None]
         )
         err = cpumod.model_error(self._models, feats, chip, mask=live)
-        self.model_errors.append(np.asarray(err))
+        self.model_errors.append(tracing.pull(err, "retrain.error", tick=t))
         # Chipless nodes have no counter model to retrain: never flagged.
         self.retrain_needed = (
-            np.asarray(
+            tracing.pull(
                 cpumod.retrain_flags(
                     self._models, feats, chip, self._retrain_cfg, mask=live
-                )
+                ),
+                "retrain.flags", tick=t,
             )
             & self._chip_mask
         )

@@ -18,6 +18,11 @@ pipeline over the streaming engine (``core.engine.streaming``):
 Dispatch order is identical with and without the drain thread, so the
 numerics are bitwise the same — the drain only moves host-side
 materialization off the dispatching thread.
+
+Each stage is a named host span (``repro.tracing``: ``faasmeter.ingest.push``,
+``faasmeter.session.dispatch``, ``faasmeter.engine.fleet_step``,
+``faasmeter.session.emit``, one ``faasmeter.pull`` per device→host
+transfer), which a JAX profiler trace records (docs/streaming.md).
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import contribution as contrib
 from repro.core import cpu_model as cpumod
 from repro.core import sync as syncmod
@@ -360,23 +366,25 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
             raise ValueError("session was created with has_chip=True")
         if self.has_cp and (cp_frac is None or sys_frac is None):
             raise ValueError("session was created with has_cp=True")
-        self._raw_w[self._n_raw] = np.asarray(w_sys, np.float32).reshape(self.b)
-        self._n_raw += 1
-        if self.has_chip:
-            # Chipless rows zeroed: whatever the caller filled them with,
-            # downstream (skew, rest-idle, combined targets, retraining)
-            # sees the chip series identically 0.
-            self._raw_chip.append(
-                np.asarray(w_chip, np.float32).reshape(self.b) * self._chip_zero
-            )
-        if self.has_cp:
-            col = contrib.shared_principal_contribution(
-                jnp.asarray(np.asarray(cp_frac, np.float32)),
-                jnp.asarray(np.asarray(sys_frac, np.float32)),
-                delta=self.cfg.delta,
-            )
-            self._cp_col.append(np.asarray(col, np.float32))
-        self._advance()
+        k = self._n_raw
+        with tracing.span("faasmeter.ingest.push", window=k):
+            self._raw_w[k] = np.asarray(w_sys, np.float32).reshape(self.b)
+            self._n_raw += 1
+            if self.has_chip:
+                # Chipless rows zeroed: whatever the caller filled them with,
+                # downstream (skew, rest-idle, combined targets, retraining)
+                # sees the chip series identically 0.
+                self._raw_chip.append(
+                    np.asarray(w_chip, np.float32).reshape(self.b) * self._chip_zero
+                )
+            if self.has_cp:
+                col = contrib.shared_principal_contribution(
+                    jnp.asarray(np.asarray(cp_frac, np.float32)),
+                    jnp.asarray(np.asarray(sys_frac, np.float32)),
+                    delta=self.cfg.delta,
+                )
+                self._cp_col.append(tracing.pull(col, "push.principal", window=k))
+            self._advance()
 
     def ingest(self, ticks, *, prefetch: int = 2, drain: bool = False) -> None:
         """Feed a whole telemetry tick stream, prefetched ahead of the engine.
@@ -561,46 +569,49 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         goes through ``_emit_tick`` — inline, or queued to the drain thread.
         """
         cfg = self.cfg
-        w_sync = self._synced_window(t)
-        self._w_sync.append(w_sync)
-        if self.combined:
-            target = self.eng.combined_rest_target(
-                jnp.asarray(w_sync),
-                jnp.asarray(self._raw_chip[t]),
-                jnp.asarray(self._rest_idle_nodes, jnp.float32),
-            )
-        else:
-            target = jnp.maximum(jnp.asarray(w_sync) - self.idle, 0.0)
-        c_t = self._c_fns[:, t]
-        j = t - self.init_n
-        a_t = self._a_win[:, j]
-        ls_t = self._ls_win[:, j]
-        lq_t = self._lq_win[:, j]
-        if self.has_cp:
-            c_t = jnp.concatenate([c_t, jnp.asarray(self._cp_col[t])[:, None]], axis=1)
-            # The principal's one pseudo-invocation per step, on its first tick.
-            p = np.full((self.b, 1), 1.0 if j % cfg.step_windows == 0 else 0.0, np.float32)
-            a_t = np.concatenate([a_t, p], axis=1)
-            z = np.zeros((self.b, 1), np.float32)
-            ls_t = np.concatenate([ls_t, z], axis=1)
-            lq_t = np.concatenate([lq_t, z], axis=1)
-        live = None
-        if self._ragged:
-            # Nodes whose stream (or sub-step tail) ended before t are
-            # masked out of the engine: zero rows into the ring buffer,
-            # frozen Kalman state, exactly-zero attribution.
-            live = t < self._n_used_nodes
-        if self._slot_pool is not None:
-            att = self._pool_tick(t, c_t, target, a_t, ls_t, lq_t, live)
-        else:
-            step = self.eng.FleetStep(
-                c=c_t, w=target,
-                a=jnp.asarray(a_t), lat_sum=jnp.asarray(ls_t), lat_sumsq=jnp.asarray(lq_t),
-                valid=None if live is None else jnp.asarray(live, jnp.float32),
-            )
-            self._state, att = self.eng.fleet_step(
-                self._state, step, config=self._engine_cfg, mesh=self.mesh
-            )
+        with tracing.span("faasmeter.session.dispatch", tick=t):
+            w_sync = self._synced_window(t)
+            self._w_sync.append(w_sync)
+            if self.combined:
+                target = self.eng.combined_rest_target(
+                    jnp.asarray(w_sync),
+                    jnp.asarray(self._raw_chip[t]),
+                    jnp.asarray(self._rest_idle_nodes, jnp.float32),
+                )
+            else:
+                target = jnp.maximum(jnp.asarray(w_sync) - self.idle, 0.0)
+            c_t = self._c_fns[:, t]
+            j = t - self.init_n
+            a_t = self._a_win[:, j]
+            ls_t = self._ls_win[:, j]
+            lq_t = self._lq_win[:, j]
+            if self.has_cp:
+                c_t = jnp.concatenate([c_t, jnp.asarray(self._cp_col[t])[:, None]], axis=1)
+                # The principal's one pseudo-invocation per step, on its first tick.
+                p = np.full((self.b, 1), 1.0 if j % cfg.step_windows == 0 else 0.0, np.float32)
+                a_t = np.concatenate([a_t, p], axis=1)
+                z = np.zeros((self.b, 1), np.float32)
+                ls_t = np.concatenate([ls_t, z], axis=1)
+                lq_t = np.concatenate([lq_t, z], axis=1)
+            live = None
+            if self._ragged:
+                # Nodes whose stream (or sub-step tail) ended before t are
+                # masked out of the engine: zero rows into the ring buffer,
+                # frozen Kalman state, exactly-zero attribution.
+                live = t < self._n_used_nodes
+            if self._slot_pool is not None:
+                with tracing.span("faasmeter.engine.fleet_step"):
+                    att = self._pool_tick(t, c_t, target, a_t, ls_t, lq_t, live)
+            else:
+                step = self.eng.FleetStep(
+                    c=c_t, w=target,
+                    a=jnp.asarray(a_t), lat_sum=jnp.asarray(ls_t), lat_sumsq=jnp.asarray(lq_t),
+                    valid=None if live is None else jnp.asarray(live, jnp.float32),
+                )
+                with tracing.span("faasmeter.engine.fleet_step"):
+                    self._state, att = self.eng.fleet_step(
+                        self._state, step, config=self._engine_cfg, mesh=self.mesh
+                    )
         # The boundary is a function of the tick index (the engine's
         # tick_in_step counter advances identically), so no device sync.
         completed = (j + 1) % cfg.step_windows == 0
@@ -620,23 +631,26 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         ``ingest(drain=True)`` — in either case ticks emit in dispatch
         order.
         """
-        if completed and self._win_feats is not None:
-            self._check_retrain(t)
-        if self.on_tick is not None:
-            self.on_tick(
-                StreamTick(
-                    t=t,
-                    x=np.asarray(att.x),
-                    tick_power=np.asarray(att.tick_power),
-                    unattributed=np.asarray(att.unattributed),
-                    busy_seconds=np.asarray(c_t),
-                    a=np.asarray(a_t),
-                    target=np.asarray(target),
-                    w_sys=w_sync,
-                    step_completed=completed,
-                    valid=live,
+        with tracing.span("faasmeter.session.emit", tick=t):
+            if completed and self._win_feats is not None:
+                self._check_retrain(t)
+            if self.on_tick is not None:
+                self.on_tick(
+                    StreamTick(
+                        t=t,
+                        x=tracing.pull(att.x, "emit.x", tick=t),
+                        tick_power=tracing.pull(att.tick_power, "emit.tick_power", tick=t),
+                        unattributed=tracing.pull(
+                            att.unattributed, "emit.unattributed", tick=t
+                        ),
+                        busy_seconds=tracing.pull(c_t, "emit.busy_seconds", tick=t),
+                        a=np.asarray(a_t),
+                        target=tracing.pull(target, "emit.target", tick=t),
+                        w_sys=w_sync,
+                        step_completed=completed,
+                        valid=live,
+                    )
                 )
-            )
 
     def _pool_tick(self, t, c_t, target, a_t, ls_t, lq_t, live):
         """Drive one engine tick through the slot pool (``slots=`` mode).
@@ -652,8 +666,8 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 node = int(i)
                 if node in pool._node_slot:
                     pool.release(node)
-        c_np = np.asarray(c_t, np.float32)
-        w_np = np.asarray(target, np.float32)
+        c_np = tracing.pull(c_t, "pool.busy_seconds", tick=t)
+        w_np = tracing.pull(target, "pool.target", tick=t)
         a_np = np.asarray(a_t, np.float32)
         ls_np = np.asarray(ls_t, np.float32)
         lq_np = np.asarray(lq_t, np.float32)

@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.configs.shapes import ShapeConfig
 from repro.models.model_zoo import ModelApi, TensorSpec, is_spec
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +73,9 @@ def prefetch_iterator(
     host stages release the GIL in their numpy/scipy kernels and in device
     transfers, which is where the overlap comes from; ``transfer`` (e.g. a
     ``jax.device_put`` wrapper) runs on the producer thread so the consumer
-    only ever sees device-resident elements.
+    only ever sees device-resident elements.  Each wait of the consumer is
+    a ``faasmeter.ingest.wait`` span (``depth``: elements queued when it
+    began), which a profiler trace records.
 
     Exceptions raised by ``it`` or ``transfer`` re-raise at the consuming
     ``next()`` call with the producer's original traceback attached.  When
@@ -122,7 +125,8 @@ def prefetch_iterator(
     producer.start()
     try:
         while True:
-            item, err = q.get()
+            with span("faasmeter.ingest.wait", depth=q.qsize()):
+                item, err = q.get()
             if item is done:
                 if err is not None:
                     raise err

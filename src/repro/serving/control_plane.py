@@ -27,6 +27,7 @@ from collections import deque
 
 import numpy as np
 
+from repro import tracing
 from repro.core.capping import (
     CappingConfig,
     FleetPowerCapController,
@@ -832,7 +833,7 @@ class EnergyFirstControlPlane:
             def _x_cpu_now():
                 n = len(session.refits)
                 if _x_cpu_cache["refits"] != n:
-                    _x_cpu_cache["v"] = np.asarray(session.x_cpu)
+                    _x_cpu_cache["v"] = tracing.pull(session.x_cpu, "control.x_cpu")
                     _x_cpu_cache["refits"] = n
                 return _x_cpu_cache["v"]
 
@@ -853,14 +854,15 @@ class EnergyFirstControlPlane:
                     )
 
             def _on_tick(tk):
-                for i, tr in enumerate(trackers):
-                    # Ragged fleet: a node whose stream has ended stops
-                    # accumulating (its engine state is frozen; folding the
-                    # dead ticks in would keep growing its idle share).
-                    if tk.valid is None or tk.valid[i]:
-                        tr.observe_tick(
-                            _full_x(tk.x[i], i), tk.busy_seconds[i], tk.a[i], cfg.delta
-                        )
+                with tracing.span("faasmeter.control.trackers", tick=tk.t):
+                    for i, tr in enumerate(trackers):
+                        # Ragged fleet: a node whose stream has ended stops
+                        # accumulating (its engine state is frozen; folding the
+                        # dead ticks in would keep growing its idle share).
+                        if tk.valid is None or tk.valid[i]:
+                            tr.observe_tick(
+                                _full_x(tk.x[i], i), tk.busy_seconds[i], tk.a[i], cfg.delta
+                            )
                 if control is not None:
                     control.on_tick(tk, trackers)
                 if on_tick is not None:

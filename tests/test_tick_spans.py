@@ -1,0 +1,187 @@
+"""Host spans on the streaming tick path (``repro.tracing``).
+
+A combined-mode ``profile_fleet`` stream runs under ``jax.profiler.trace``
+and the recorded ``.xplane.pb`` is read back with ``ProfileData``.  Pinned:
+
+- every span of the contract is recorded;
+- ``faasmeter.session.dispatch``, ``faasmeter.session.emit`` and
+  ``faasmeter.control.trackers`` occur once per emitted tick, with the
+  ``tick`` values the ``on_tick`` hook saw;
+- ``faasmeter.engine.fleet_step`` lies inside ``faasmeter.session.dispatch``;
+- one ``faasmeter.pull`` per device->host transfer the code path makes;
+- the ticks are bitwise the same with and without a trace;
+- under ``ingest(drain=True)`` the emit spans sit on the drain thread.
+"""
+
+from __future__ import annotations
+
+import collections
+import threading
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core.profiler import ProfilerConfig
+from repro.serving.control_plane import EnergyFirstControlPlane
+from repro.telemetry.simulator import SimulatorConfig
+from repro.workload.azure import WorkloadConfig, generate_trace
+from repro.workload.functions import paper_functions
+
+DURATION = 150.0  # 60 init windows + 3 Kalman steps of 30
+INIT, STEP = 60, 30
+TICKS = list(range(INIT, int(DURATION)))
+FIELDS = ("x", "tick_power", "unattributed", "busy_seconds", "a", "target", "w_sys")
+
+
+def _run(trace_dir=None, drain=False, hook=None):
+    """One combined-mode stream of two server nodes; returns its ticks."""
+    reg = paper_functions()
+    cp = EnergyFirstControlPlane(
+        reg, SimulatorConfig(platform="server", seed=0),
+        ProfilerConfig(init_windows=INIT, step_windows=STEP, mode="combined"),
+    )
+    traces = [
+        generate_trace(reg, WorkloadConfig(duration_s=DURATION, load=1.0, seed=s))
+        for s in (3, 4)
+    ]
+    ticks = []
+
+    def on_tick(tk, trackers):
+        ticks.append(tk)
+        if hook is not None:
+            hook(tk)
+
+    def go():
+        cp.profile_fleet(traces, seeds=[21, 22], mode="combined", mesh=None,
+                         on_tick=on_tick, drain=drain)
+
+    if trace_dir is None:
+        go()
+        return ticks, None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(trace_dir), profiler_options=opts):
+        go()
+    return ticks, _spans(trace_dir)
+
+
+def _spans(trace_dir):
+    """Per host thread line: [(name, start_ns, end_ns, stats)] of the
+    ``faasmeter.*`` and ``test.*`` spans."""
+    from jax.profiler import ProfileData
+
+    (path,) = Path(trace_dir).rglob("*.xplane.pb")
+    lines = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                  for e in line.events if e.name.startswith(("faasmeter.", "test."))]
+            if ev:
+                lines.append(ev)
+    return lines
+
+
+@pytest.fixture(scope="module")
+def untraced():
+    return _run()[0]
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return _run(tmp_path_factory.mktemp("trace"))
+
+
+def _named(lines, name):
+    return [ev for line in lines for ev in line if ev[0] == name]
+
+
+def test_every_span_of_the_contract_is_recorded(traced):
+    _, lines = traced
+    names = {ev[0] for line in lines for ev in line}
+    assert names == {
+        "faasmeter.ingest.wait", "faasmeter.ingest.push", "faasmeter.session.dispatch",
+        "faasmeter.engine.fleet_step", "faasmeter.session.emit", "faasmeter.pull",
+        "faasmeter.control.trackers",
+    }
+    waits = _named(lines, "faasmeter.ingest.wait")
+    assert all(0 <= ev[3]["depth"] <= 2 for ev in waits)
+    pushes = _named(lines, "faasmeter.ingest.push")
+    assert sorted(ev[3]["window"] for ev in pushes) == list(range(int(DURATION)))
+
+
+@pytest.mark.parametrize("name", ["faasmeter.session.dispatch", "faasmeter.session.emit",
+                                  "faasmeter.control.trackers"])
+def test_one_span_per_emitted_tick(traced, name):
+    ticks, lines = traced
+    assert [tk.t for tk in ticks] == TICKS
+    assert sorted(ev[3]["tick"] for ev in _named(lines, name)) == TICKS
+
+
+def test_fleet_step_lies_inside_dispatch(traced):
+    _, lines = traced
+    for line in lines:
+        dispatch = [ev for ev in line if ev[0] == "faasmeter.session.dispatch"]
+        steps = [ev for ev in line if ev[0] == "faasmeter.engine.fleet_step"]
+        for _, s, e, _ in steps:
+            assert sum(ds <= s and e <= de for _, ds, de, _ in dispatch) == 1
+    assert len(_named(lines, "faasmeter.engine.fleet_step")) == len(TICKS)
+
+
+def test_one_pull_per_device_transfer(traced):
+    """Each pushed window pulls its principal column; each emitted tick
+    pulls five arrays (``a`` is host data already); each completed Kalman
+    step pulls the retrain check's error and flags; the trackers pull X_CPU
+    once, at bootstrap."""
+    _, lines = traced
+    pulls = _named(lines, "faasmeter.pull")
+    by_site = collections.Counter(ev[3]["site"] for ev in pulls)
+    steps = len(TICKS) // STEP
+    assert by_site == {
+        "push.principal": int(DURATION),
+        "emit.x": len(TICKS), "emit.tick_power": len(TICKS),
+        "emit.unattributed": len(TICKS), "emit.busy_seconds": len(TICKS),
+        "emit.target": len(TICKS),
+        "retrain.error": steps, "retrain.flags": steps,
+        "control.x_cpu": 1,
+    }
+    emitted = {ev[3]["tick"] for ev in pulls if ev[3]["site"].startswith("emit.")}
+    assert emitted == set(TICKS)
+    # Each pull sits inside the span that made it, on the same thread.
+    for line in lines:
+        emits = [ev for ev in line if ev[0] == "faasmeter.session.emit"]
+        for _, s, e, meta in line:
+            if meta.get("site", "").startswith("emit."):
+                assert any(es <= s and e <= ee and em["tick"] == meta["tick"]
+                           for _, es, ee, em in emits)
+
+
+def test_traced_ticks_equal_untraced_bitwise(traced, untraced):
+    ticks, _ = traced
+    assert [tk.t for tk in ticks] == [tk.t for tk in untraced]
+    for a, b in zip(ticks, untraced):
+        assert a.step_completed == b.step_completed
+        for f in FIELDS:
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f), err_msg=f)
+
+
+def test_drained_emit_spans_sit_on_the_drain_thread(tmp_path):
+    threads = set()
+
+    def hook(tk):
+        threads.add(threading.current_thread().name)
+        with jax.profiler.TraceAnnotation("test.hook", tick=tk.t):
+            pass
+
+    ticks, lines = _run(tmp_path, drain=True, hook=hook)
+    assert [tk.t for tk in ticks] == TICKS and threads == {"session-drain"}
+    (drain,) = [line for line in lines if any(ev[0] == "test.hook" for ev in line)]
+    assert sorted(ev[3]["tick"] for ev in drain if ev[0] == "faasmeter.session.emit") == TICKS
+    assert not any(ev[0] == "faasmeter.session.dispatch" for ev in drain)
+    emits = [ev for ev in drain if ev[0] == "faasmeter.session.emit"]
+    for _, s, e, meta in (ev for ev in drain if ev[0] == "test.hook"):
+        assert any(es <= s and e <= ee and em["tick"] == meta["tick"]
+                   for _, es, ee, em in emits)
