@@ -281,6 +281,10 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 a0 = jnp.concatenate([a0, jnp.ones((1,))])
             init_a.append(a0)
         self._c_fns = jnp.stack(c_nodes)         # (B, N, M)
+        # Host copy for the tick path: each tick's row is host data the
+        # engine step and the ``on_tick`` consumer share, with no eager
+        # device slice and no pull back.
+        self._c_host = np.asarray(self._c_fns)
         self._a_win = np.stack([np.asarray(a) for a in a_nodes])    # (B, n_post, M)
         self._ls_win = np.stack([np.asarray(a) for a in ls_nodes])
         self._lq_win = np.stack([np.asarray(a) for a in lq_nodes])
@@ -580,19 +584,22 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 )
             else:
                 target = jnp.maximum(jnp.asarray(w_sync) - self.idle, 0.0)
-            c_t = self._c_fns[:, t]
             j = t - self.init_n
             a_t = self._a_win[:, j]
             ls_t = self._ls_win[:, j]
             lq_t = self._lq_win[:, j]
+            # A fresh (B, M_aug) array every tick, never a view of the host
+            # copy: hooks receive it as ``busy_seconds``.
             if self.has_cp:
-                c_t = jnp.concatenate([c_t, jnp.asarray(self._cp_col[t])[:, None]], axis=1)
+                c_t = np.concatenate([self._c_host[:, t], self._cp_col[t][:, None]], axis=1)
                 # The principal's one pseudo-invocation per step, on its first tick.
                 p = np.full((self.b, 1), 1.0 if j % cfg.step_windows == 0 else 0.0, np.float32)
                 a_t = np.concatenate([a_t, p], axis=1)
                 z = np.zeros((self.b, 1), np.float32)
                 ls_t = np.concatenate([ls_t, z], axis=1)
                 lq_t = np.concatenate([lq_t, z], axis=1)
+            else:
+                c_t = self._c_host[:, t].copy()
             live = None
             if self._ragged:
                 # Nodes whose stream (or sub-step tail) ended before t are
@@ -643,7 +650,7 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                         unattributed=tracing.pull(
                             att.unattributed, "emit.unattributed", tick=t
                         ),
-                        busy_seconds=tracing.pull(c_t, "emit.busy_seconds", tick=t),
+                        busy_seconds=c_t,
                         a=np.asarray(a_t),
                         target=tracing.pull(target, "emit.target", tick=t),
                         w_sys=w_sync,
@@ -666,14 +673,13 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 node = int(i)
                 if node in pool._node_slot:
                     pool.release(node)
-        c_np = tracing.pull(c_t, "pool.busy_seconds", tick=t)
         w_np = tracing.pull(target, "pool.target", tick=t)
         a_np = np.asarray(a_t, np.float32)
         ls_np = np.asarray(ls_t, np.float32)
         lq_np = np.asarray(lq_t, np.float32)
         live_nodes = range(self.b) if live is None else np.nonzero(live)[0]
         feeds = {
-            int(i): (c_np[i], w_np[i], a_np[i], ls_np[i], lq_np[i])
+            int(i): (c_t[i], w_np[i], a_np[i], ls_np[i], lq_np[i])
             for i in live_nodes
         }
         att = pool.step(feeds)

@@ -9,6 +9,8 @@ and the recorded ``.xplane.pb`` is read back with ``ProfileData``.  Pinned:
   ``tick`` values the ``on_tick`` hook saw;
 - ``faasmeter.engine.fleet_step`` lies inside ``faasmeter.session.dispatch``;
 - one ``faasmeter.pull`` per device->host transfer the code path makes;
+- each dispatch launches two device programs, the combined target and the
+  engine step (JAX's ``PjitFunction(...)`` host events);
 - the ticks are bitwise the same with and without a trace;
 - under ``ingest(drain=True)`` the emit spans sit on the drain thread.
 """
@@ -35,7 +37,7 @@ TICKS = list(range(INIT, int(DURATION)))
 FIELDS = ("x", "tick_power", "unattributed", "busy_seconds", "a", "target", "w_sys")
 
 
-def _run(trace_dir=None, drain=False, hook=None):
+def _run(trace_dir=None, drain=False, hook=None, slots=None):
     """One combined-mode stream of two server nodes; returns its ticks."""
     reg = paper_functions()
     cp = EnergyFirstControlPlane(
@@ -55,7 +57,7 @@ def _run(trace_dir=None, drain=False, hook=None):
 
     def go():
         cp.profile_fleet(traces, seeds=[21, 22], mode="combined", mesh=None,
-                         on_tick=on_tick, drain=drain)
+                         on_tick=on_tick, drain=drain, slots=slots)
 
     if trace_dir is None:
         go()
@@ -69,7 +71,8 @@ def _run(trace_dir=None, drain=False, hook=None):
 
 def _spans(trace_dir):
     """Per host thread line: [(name, start_ns, end_ns, stats)] of the
-    ``faasmeter.*`` and ``test.*`` spans."""
+    ``faasmeter.*`` and ``test.*`` spans and JAX's ``PjitFunction(...)``
+    launches."""
     from jax.profiler import ProfileData
 
     (path,) = Path(trace_dir).rglob("*.xplane.pb")
@@ -79,7 +82,7 @@ def _spans(trace_dir):
             continue
         for line in plane.lines:
             ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
-                  for e in line.events if e.name.startswith(("faasmeter.", "test."))]
+                  for e in line.events if e.name.startswith(("faasmeter.", "test.", "PjitFunction("))]
             if ev:
                 lines.append(ev)
     return lines
@@ -101,7 +104,7 @@ def _named(lines, name):
 
 def test_every_span_of_the_contract_is_recorded(traced):
     _, lines = traced
-    names = {ev[0] for line in lines for ev in line}
+    names = {ev[0] for line in lines for ev in line if ev[0].startswith("faasmeter.")}
     assert names == {
         "faasmeter.ingest.wait", "faasmeter.ingest.push", "faasmeter.session.dispatch",
         "faasmeter.engine.fleet_step", "faasmeter.session.emit", "faasmeter.pull",
@@ -131,11 +134,35 @@ def test_fleet_step_lies_inside_dispatch(traced):
     assert len(_named(lines, "faasmeter.engine.fleet_step")) == len(TICKS)
 
 
+def test_dispatch_launches_two_device_programs(traced):
+    """Each tick's dispatch launches the combined rest target and the engine
+    step, and nothing else: the contribution row is host data, so no eager
+    slice, squeeze, broadcast or concatenate of it on the device.  JAX
+    records a launch as nested ``PjitFunction(...)`` events (and traces the
+    program's own calls under the first), so the outermost ones count."""
+    _, lines = traced
+    per_tick = {}
+    for line in lines:
+        launches = [ev for ev in line if ev[0].startswith("PjitFunction(")]
+        for _, s, e, meta in (ev for ev in line if ev[0] == "faasmeter.session.dispatch"):
+            inside = sorted((ev for ev in launches if s <= ev[1] and ev[2] <= e),
+                            key=lambda ev: (ev[1], -ev[2]))
+            outermost, end = [], -1
+            for name, ls, le, _ in inside:
+                if ls >= end:
+                    outermost.append(name)
+                    end = le
+            per_tick[meta["tick"]] = sorted(outermost)
+    assert sorted(per_tick) == TICKS
+    want = ["PjitFunction(_fleet_step_impl)", "PjitFunction(combined_rest_target)"]
+    assert all(names == want for names in per_tick.values()), per_tick[TICKS[0]]
+
+
 def test_one_pull_per_device_transfer(traced):
     """Each pushed window pulls its principal column; each emitted tick
-    pulls five arrays (``a`` is host data already); each completed Kalman
-    step pulls the retrain check's error and flags; the trackers pull X_CPU
-    once, at bootstrap."""
+    pulls four arrays (``a`` and ``busy_seconds`` are host data already);
+    each completed Kalman step pulls the retrain check's error and flags;
+    the trackers pull X_CPU once, at bootstrap."""
     _, lines = traced
     pulls = _named(lines, "faasmeter.pull")
     by_site = collections.Counter(ev[3]["site"] for ev in pulls)
@@ -143,8 +170,7 @@ def test_one_pull_per_device_transfer(traced):
     assert by_site == {
         "push.principal": int(DURATION),
         "emit.x": len(TICKS), "emit.tick_power": len(TICKS),
-        "emit.unattributed": len(TICKS), "emit.busy_seconds": len(TICKS),
-        "emit.target": len(TICKS),
+        "emit.unattributed": len(TICKS), "emit.target": len(TICKS),
         "retrain.error": steps, "retrain.flags": steps,
         "control.x_cpu": 1,
     }
@@ -157,6 +183,23 @@ def test_one_pull_per_device_transfer(traced):
             if meta.get("site", "").startswith("emit."):
                 assert any(es <= s and e <= ee and em["tick"] == meta["tick"]
                            for _, es, ee, em in emits)
+
+
+def test_slot_mode_pulls_the_target_alone(tmp_path):
+    """Through the slot pool each tick pulls its target to fill the pool's
+    feeds; the contribution row is host data there too, so no
+    ``pool.busy_seconds`` pull."""
+    ticks, lines = _run(tmp_path, slots=3)
+    assert [tk.t for tk in ticks] == TICKS
+    by_site = collections.Counter(ev[3]["site"] for ev in _named(lines, "faasmeter.pull"))
+    steps = len(TICKS) // STEP
+    assert by_site == {
+        "push.principal": int(DURATION), "pool.target": len(TICKS),
+        "emit.x": len(TICKS), "emit.tick_power": len(TICKS),
+        "emit.unattributed": len(TICKS), "emit.target": len(TICKS),
+        "retrain.error": steps, "retrain.flags": steps,
+        "control.x_cpu": 1,
+    }
 
 
 def test_traced_ticks_equal_untraced_bitwise(traced, untraced):
