@@ -15,7 +15,8 @@ gram-hoisted, streaming step).  Module DAG, imports only downward:
     plan         FleetPlan: resolve_plan / finish_result / segment_plan
     sharding     shard_map dispatch of any stage over a FleetMesh
     segment      run_fleet / run_fleet_gram / run_fleet_sequential
-    streaming    fleet_step / fleet_stream_reset_slots / run_fleet_stream
+    streaming    fleet_step / pack_tick_feed / tick_feed /
+                 fleet_stream_reset_slots / run_fleet_stream
     packing      per-window host arrays → (B, S, n_w, ...) batches
     buckets      AOT-warmable compile shapes for serving
 
@@ -81,7 +82,9 @@ from repro.core.engine.streaming import (
     fleet_stream_init,
     fleet_stream_reset_slots,
     fleet_ticks,
+    pack_tick_feed,
     run_fleet_stream,
+    tick_feed,
 )
 from repro.core.engine.targets import combined_rest_target, fleet_rest_idle
 from repro.core.engine.types import (
@@ -120,6 +123,7 @@ __all__ = [
     "fold_step_valid",
     "pack_fleet_buckets",
     "pack_fleet_inputs",
+    "pack_tick_feed",
     "pad_waste_frac",
     "resolve_plan",
     "run_fleet",
@@ -131,5 +135,6 @@ __all__ = [
     "synthetic_fleet",
     "synthetic_ragged_windows",
     "tick_attribution",
+    "tick_feed",
     "warm_bucket_solvers",
 ]

@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.engine.estimate import _init_states
 from repro.core.engine.masking import _apply_mask, fold_step_valid
@@ -31,6 +32,7 @@ from repro.core.engine.sharding import (
     _sharded_step_runner,
 )
 from repro.core.engine.attribution import _conserved_split
+from repro.core.engine.targets import combined_rest_target
 from repro.core.engine.types import (
     Array,
     EngineConfig,
@@ -188,6 +190,40 @@ state (in place, and still sharded when a ``FleetMesh`` is active), so the
 caller must rebind (``state, att = fleet_step(state, step, ...)``) and must
 not touch the old state afterwards.
 """
+
+
+def pack_tick_feed(w_sync, chip, idle, c, a, lat_sum, lat_sumsq, valid=None) -> np.ndarray:
+    """One tick's whole feed as one float32 host array, the layout
+    ``tick_feed`` unpacks: (B, 3 + 4 m), one more column with ``valid``.
+
+    The synced system window, the chip window and the rest idle, then the
+    contribution, invocation, latency-sum and latency-sum-of-squares rows
+    (m columns each), then the liveness flag.  A pure-mode feed carries a
+    zero chip column and the node's idle, which gives the target
+    ``max(W_sys - idle, 0)`` exactly.
+    """
+    cols = [w_sync[:, None], chip[:, None], idle[:, None], c, a, lat_sum, lat_sumsq]
+    if valid is not None:
+        cols.append(valid[:, None])
+    return np.concatenate(cols, axis=1, dtype=np.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "has_valid"))
+def tick_feed(packed: Array, *, m: int, has_valid: bool) -> FleetStep:
+    """One tick's ``FleetStep`` from the array ``pack_tick_feed`` built.
+
+    ``w`` is the combined rest target (§4.3), ``combined_rest_target`` of
+    the first three columns.  One host->device transfer then carries the
+    tick.  Under a ``FleetMesh`` it arrives node-sharded and every device
+    unpacks its own nodes, so each leaf reaches ``fleet_step`` in its node
+    shards with no collective.
+    """
+    rows = [packed[:, 3 + k * m:3 + (k + 1) * m] for k in range(4)]
+    return FleetStep(
+        c=rows[0], w=combined_rest_target(packed[:, 0], packed[:, 1], packed[:, 2]),
+        a=rows[1], lat_sum=rows[2], lat_sumsq=rows[3],
+        valid=packed[:, 3 + 4 * m] if has_valid else None,
+    )
 
 
 def _reset_slots_local(

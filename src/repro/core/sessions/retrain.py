@@ -38,10 +38,11 @@ class RetrainMixin:
         defined).  Dead (ragged) nodes score only their real windows; a
         node with none stays un-flagged."""
         lo, hi = t - self.cfg.step_windows + 1, t + 1
-        feats = jnp.asarray(self._win_feats[:, lo:hi])             # (B, n_w, F)
-        chip = jnp.asarray(np.stack(self._raw_chip[lo:hi], axis=1))  # (B, n_w)
-        live = jnp.asarray(
-            np.arange(lo, hi)[None, :] < self._n_nodes[:, None]
+        # On the default device, beside the counter models they score.
+        feats = tracing.put(self._win_feats[:, lo:hi], "retrain.features", tick=t)
+        chip = tracing.put(np.stack(self._raw_chip[lo:hi], axis=1), "retrain.chip", tick=t)
+        live = tracing.put(
+            np.arange(lo, hi)[None, :] < self._n_nodes[:, None], "retrain.live", tick=t
         )
         err = cpumod.model_error(self._models, feats, chip, mask=live)
         self.model_errors.append(tracing.pull(err, "retrain.error", tick=t))

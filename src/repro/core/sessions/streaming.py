@@ -247,7 +247,8 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         # apply_shift clamp), never read into another node's span.
         self._n_nodes = np.asarray([p[0] for p in plans], np.float64)
         self.m_aug = num_fns + (1 if has_cp else 0)
-        self.idle = jnp.asarray(np.asarray(idle_watts, np.float32))
+        self._idle_host = np.asarray(idle_watts, np.float32)
+        self.idle = jnp.asarray(self._idle_host)
         self.init_seconds = self.init_n * cfg.delta
 
         # Static per-node precomputation (the trace is known; telemetry is
@@ -383,8 +384,8 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 )
             if self.has_cp:
                 col = contrib.shared_principal_contribution(
-                    jnp.asarray(np.asarray(cp_frac, np.float32)),
-                    jnp.asarray(np.asarray(sys_frac, np.float32)),
+                    tracing.put(np.asarray(cp_frac, np.float32), "push.cp_frac", window=k),
+                    tracing.put(np.asarray(sys_frac, np.float32), "push.sys_frac", window=k),
                     delta=self.cfg.delta,
                 )
                 self._cp_col.append(tracing.pull(col, "push.principal", window=k))
@@ -562,6 +563,25 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         col = jnp.asarray(np.stack(self._cp_col[lo:hi], axis=1))   # (B, hi-lo)
         return jnp.concatenate([block, col[:, :, None]], axis=2)
 
+    def _rest_target(self, t: int, w_sync: np.ndarray) -> Array:
+        """Tick ``t``'s disaggregation target on the default device."""
+        w = tracing.put(w_sync, "dispatch.w_sync", tick=t)
+        if not self.combined:
+            return jnp.maximum(w - self.idle, 0.0)
+        return self.eng.combined_rest_target(
+            w,
+            tracing.put(self._raw_chip[t], "dispatch.chip", tick=t),
+            tracing.put(self._rest_idle_nodes, "dispatch.rest_idle", tick=t),
+        )
+
+    def _packed_feed(self, t, w_sync, c_t, a_t, ls_t, lq_t, live) -> np.ndarray:
+        """Tick ``t``'s whole feed as one host array (``engine.pack_tick_feed``)."""
+        if self.combined:
+            chip, idle = self._raw_chip[t], self._rest_idle_nodes
+        else:
+            chip, idle = np.zeros(self.b, np.float32), self._idle_host
+        return self.eng.pack_tick_feed(w_sync, chip, idle, c_t, a_t, ls_t, lq_t, live)
+
     def _process_tick(self, t: int) -> None:
         """Dispatch stage: build tick ``t``'s feed and launch the engine step.
 
@@ -576,14 +596,6 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         with tracing.span("faasmeter.session.dispatch", tick=t):
             w_sync = self._synced_window(t)
             self._w_sync.append(w_sync)
-            if self.combined:
-                target = self.eng.combined_rest_target(
-                    jnp.asarray(w_sync),
-                    jnp.asarray(self._raw_chip[t]),
-                    jnp.asarray(self._rest_idle_nodes, jnp.float32),
-                )
-            else:
-                target = jnp.maximum(jnp.asarray(w_sync) - self.idle, 0.0)
             j = t - self.init_n
             a_t = self._a_win[:, j]
             ls_t = self._ls_win[:, j]
@@ -607,14 +619,32 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
                 # frozen Kalman state, exactly-zero attribution.
                 live = t < self._n_used_nodes
             if self._slot_pool is not None:
+                target = self._rest_target(t, w_sync)
                 with tracing.span("faasmeter.engine.fleet_step"):
                     att = self._pool_tick(t, c_t, target, a_t, ls_t, lq_t, live)
             else:
-                step = self.eng.FleetStep(
-                    c=c_t, w=target,
-                    a=jnp.asarray(a_t), lat_sum=jnp.asarray(ls_t), lat_sumsq=jnp.asarray(lq_t),
-                    valid=None if live is None else jnp.asarray(live, jnp.float32),
-                )
+                if self.mesh is None:
+                    target = self._rest_target(t, w_sync)
+                    step = self.eng.FleetStep(
+                        c=tracing.put(c_t, "dispatch.c", tick=t), w=target,
+                        a=tracing.put(a_t, "dispatch.a", tick=t),
+                        lat_sum=tracing.put(ls_t, "dispatch.lat_sum", tick=t),
+                        lat_sumsq=tracing.put(lq_t, "dispatch.lat_sumsq", tick=t),
+                        valid=None if live is None else tracing.put(
+                            live.astype(np.float32), "dispatch.valid", tick=t),
+                    )
+                else:
+                    # A put into node shards costs one host->device copy per
+                    # device whatever its size, so the whole feed goes in
+                    # one; each device unpacks its own nodes and computes
+                    # their target, and every leaf reaches the step in its
+                    # node shards.
+                    feed = self._packed_feed(t, w_sync, c_t, a_t, ls_t, lq_t, live)
+                    step = self.eng.tick_feed(
+                        tracing.put(feed, "dispatch.feed", self.mesh.node_sharding(), tick=t),
+                        m=self.m_aug, has_valid=live is not None,
+                    )
+                    target = step.w
                 with tracing.span("faasmeter.engine.fleet_step"):
                     self._state, att = self.eng.fleet_step(
                         self._state, step, config=self._engine_cfg, mesh=self.mesh

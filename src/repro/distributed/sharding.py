@@ -53,6 +53,7 @@ from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
@@ -295,11 +296,12 @@ class FleetMesh:
         state's ``tick_in_step``/``step_idx`` counters) are replicated.
         Donated state placed this way stays sharded in place across
         ``fleet_step`` calls — no gather ever materializes the full fleet
-        on one device.
+        on one device.  A numpy leaf is split on the host and each block
+        sent to its own device; it never passes through the default device.
         """
 
         def _place(leaf):
-            arr = jnp.asarray(leaf)
+            arr = leaf if isinstance(leaf, np.ndarray) else jnp.asarray(leaf)
             if arr.ndim == 0:
                 return jax.device_put(arr, self.replicated_sharding())
             self.validate(arr.shape[0])
@@ -327,8 +329,6 @@ def fleet_mesh(
     mesh is the identity sharding, which is what lets every ``mesh=`` code
     path run (and be tested) without multi-device hardware.
     """
-    import numpy as np
-
     devs = list(jax.devices() if devices is None else devices)
     d = len(devs)
     if num_nodes is not None:

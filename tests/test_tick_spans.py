@@ -9,6 +9,7 @@ and the recorded ``.xplane.pb`` is read back with ``ProfileData``.  Pinned:
   ``tick`` values the ``on_tick`` hook saw;
 - ``faasmeter.engine.fleet_step`` lies inside ``faasmeter.session.dispatch``;
 - one ``faasmeter.pull`` per device->host transfer the code path makes;
+- one ``faasmeter.put`` per host->device transfer, each on one device;
 - each dispatch launches two device programs, the combined target and the
   engine step (JAX's ``PjitFunction(...)`` host events);
 - the ticks are bitwise the same with and without a trace;
@@ -108,7 +109,7 @@ def test_every_span_of_the_contract_is_recorded(traced):
     assert names == {
         "faasmeter.ingest.wait", "faasmeter.ingest.push", "faasmeter.session.dispatch",
         "faasmeter.engine.fleet_step", "faasmeter.session.emit", "faasmeter.pull",
-        "faasmeter.control.trackers",
+        "faasmeter.put", "faasmeter.control.trackers",
     }
     waits = _named(lines, "faasmeter.ingest.wait")
     assert all(0 <= ev[3]["depth"] <= 2 for ev in waits)
@@ -183,6 +184,32 @@ def test_one_pull_per_device_transfer(traced):
             if meta.get("site", "").startswith("emit."):
                 assert any(es <= s and e <= ee and em["tick"] == meta["tick"]
                            for _, es, ee, em in emits)
+
+
+def test_one_put_per_host_transfer(traced):
+    """Each pushed window puts the principal's two CPU fractions; each
+    dispatched tick puts the rest target's three inputs and the step's four
+    rows; each completed Kalman step puts the retrain check's inputs.
+    Without a mesh every put lands on one device, and a tick's puts lie
+    inside its dispatch span."""
+    _, lines = traced
+    puts = _named(lines, "faasmeter.put")
+    assert all(ev[3]["shards"] == 1 for ev in puts)
+    by_site = collections.Counter(ev[3]["site"] for ev in puts)
+    steps = len(TICKS) // STEP
+    dispatch = ("w_sync", "chip", "rest_idle", "c", "a", "lat_sum", "lat_sumsq")
+    assert by_site == {
+        "push.cp_frac": int(DURATION), "push.sys_frac": int(DURATION),
+        **{f"dispatch.{leaf}": len(TICKS) for leaf in dispatch},
+        "retrain.features": steps, "retrain.chip": steps, "retrain.live": steps,
+    }
+    for line in lines:
+        ticks = {em["tick"]: (ds, de) for name, ds, de, em in line
+                 if name == "faasmeter.session.dispatch"}
+        for name, s, e, meta in line:
+            if name == "faasmeter.put" and meta["site"].startswith("dispatch."):
+                ds, de = ticks[meta["tick"]]
+                assert ds <= s and e <= de
 
 
 def test_slot_mode_pulls_the_target_alone(tmp_path):

@@ -3,8 +3,9 @@
 The TPU compiler is installed on CPU-only hosts too: it compiles for a chip
 described by ``jax.experimental.topologies``.  These tests run the chip's
 compiler over the fleet controller's hot programs at deployment widths —
-the Pallas gram kernel, the streaming ``fleet_step``, and the gram-hoisted
-segment program — so a kernel Mosaic refuses (an unaligned block, too much
+the Pallas gram kernel, the streaming ``fleet_step`` on one chip and
+sharded over the four chips of a host, and the gram-hoisted segment
+program — so a kernel Mosaic refuses (an unaligned block, too much
 VMEM) or a program that does not fit the chip's memory fails here, not on
 the chip.  Nothing runs; results and times come only from a chip run
 (``chip_smoke.py``).
@@ -27,6 +28,7 @@ from repro.core.engine import (
     fleet_stream_init,
     run_fleet_gram,
 )
+from repro.distributed.sharding import FLEET_AXIS, FleetMesh
 from repro.kernels.disagg_solve import disagg_gram
 
 #: chip_smoke.py's fleet: 64 nodes, 18 Kalman steps of 30 windows, the
@@ -36,23 +38,37 @@ HBM_BYTES = 16 * 2**30  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One v5e chip of a described 2x2 topology, persistent cache off."""
+def topo():
+    """A described v5e 2x2 topology (four chips), persistent cache off."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
         # A compile for a described chip is written to the persistent cache
         # but cannot be read back without the chip: keep the cache out.
         was_on = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield desc
         finally:
             jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """A ``FleetMesh`` over the host's four described chips."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return FleetMesh(mesh=Mesh(np.asarray(topo.devices), (FLEET_AXIS,)))
 
 
 def _shape(shape, sharding):
@@ -96,6 +112,33 @@ def test_fleet_step_compiles(one_chip, b, m):
         lat_sumsq=_shape((b, m), one_chip),
     )
     compiled = _compile_all_highest(fleet_step.lower(state, step, config=cfg))
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+
+
+def test_sharded_fleet_step_compiles(four_chips):
+    """``fleet_step`` under ``shard_map`` at the four-chip cell's fleet,
+    1,024 nodes, 256 per chip: every input in its node shards and no
+    collective in the program."""
+    b, m = 1024, 8
+    node, rep = four_chips.node_sharding(), four_chips.replicated_sharding()
+    cfg = EngineConfig()
+    state = jax.eval_shape(
+        lambda x0: fleet_stream_init(x0, N_W, cfg),
+        jax.ShapeDtypeStruct((b, m), jnp.float32),
+    )
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=node if s.ndim else rep),
+        state,
+    )
+    step = FleetStep(
+        c=_shape((b, m), node), w=_shape((b,), node), a=_shape((b, m), node),
+        lat_sum=_shape((b, m), node), lat_sumsq=_shape((b, m), node),
+    )
+    compiled = _compile_all_highest(fleet_step.lower(state, step, config=cfg, mesh=four_chips))
+    assert all(s == node for s in compiled.input_shardings[0][1] if s is not None)
+    text = compiled.as_text()
+    assert not any(op in text for op in ("all-reduce", "all-gather", "collective-permute",
+                                         "all-to-all", "reduce-scatter"))
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
 
 
