@@ -450,13 +450,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     metrics = {}
     breakdown = None
     if trace:
+        import host_spans
         import trace_reduce
 
-        red = trace_reduce.reduce(trace_reduce.load(tracer.path()))
+        path = tracer.path()
+        red = trace_reduce.reduce(trace_reduce.load(path))
+        ticks_traced = int(((at >= t_open) & (at < t_close)).sum())
+        host = host_spans.per_tick(host_spans.reduce(host_spans.load(path)), ticks_traced)
         if tmp is not None:
             tmp.cleanup()
-        ticks_traced = int(((at >= t_open) & (at < t_close)).sum())
-        ctx = {"reduced": red, "ticks": ticks_traced, "nodes": b, "cell": cell.name}
+        ctx = {"reduced": red, "host": host, "ticks": ticks_traced, "nodes": b,
+               "cell": cell.name}
         for m in cell.per_layer:
             v = metric_reader(m["name"])(ctx)
             if v is not None:
@@ -466,6 +470,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         breakdown = red["breakdown"]
         log(f"trace: busy {red['busy_s']:.6f} s of {red['window_s']:.6f} s, "
             f"{ticks_traced} ticks, programs {json.dumps(red['programs'])}")
+        log(f"trace: host per tick {json.dumps(host)}")
     else:
         for m in cell.end_to_end:
             if values[m["name"]] is None:

@@ -52,6 +52,7 @@ PREFIX = "faasmeter."
 DISPATCH = "faasmeter.session.dispatch"
 WAIT = "faasmeter.ingest.wait"
 PULL = "faasmeter.pull"
+PUT = "faasmeter.put"
 ON_TICK = "bench.on_tick"
 
 # reading -> (spans it sums, which of their times), in microseconds per tick
@@ -60,6 +61,7 @@ LAYERS = {
     "session_host_us": ((DISPATCH, "faasmeter.session.emit"), "self_seconds"),
     "fleet_step_host_us": (("faasmeter.engine.fleet_step",), "seconds"),
     "device_pull_us": ((PULL,), "seconds"),
+    "device_put_us": ((PUT,), "seconds"),
     "tracker_host_us": (("faasmeter.control.trackers",), "self_seconds"),
 }
 
