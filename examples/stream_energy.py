@@ -9,8 +9,8 @@ bootstraps X_0 on the init segment and then advances the jitted streaming
 engine (``fleet_step``) tick by tick.  The ``on_tick`` hook shows what an
 energy-first control plane does *during* the segment, not after it:
 
-- folds every tick's causal attribution into per-node
-  ``StreamingFootprintTracker``s (live J/invocation);
+- folds every tick's causal attribution into one
+  ``FleetFootprintTracker`` in one call (live J/invocation per node);
 - prices the running footprints (live $/invocation);
 - feeds attributed fleet power to a ``PowerCapController`` and reports
   would-be admission decisions against a software cap.
@@ -21,7 +21,7 @@ import numpy as np
 from repro.compile_cache import enable_compile_cache
 from repro.core.capping import CappingConfig, PowerCapController
 from repro.core.pricing import energy_price_usd
-from repro.serving.control_plane import StreamingFootprintTracker
+from repro.serving import FleetFootprintTracker
 from repro.telemetry.simulator import NodeSimulator, SimulatorConfig
 from repro.workload.azure import WorkloadConfig, generate_trace
 from repro.workload.functions import paper_functions
@@ -47,7 +47,7 @@ def main():
     profiler = FaasMeterProfiler(ProfilerConfig(init_windows=60, step_windows=30))
     num_fns = traces[0].num_fns
     idle_w = sim.power_cfg.idle_w
-    trackers = [StreamingFootprintTracker(num_fns, idle_watts=idle_w) for _ in range(NODES)]
+    trackers = FleetFootprintTracker(num_fns, idle_watts=[idle_w] * NODES)
     cap = PowerCapController(
         CappingConfig(power_cap_watts=CAP_WATTS, control_interval_s=1.0)
     )
@@ -60,17 +60,15 @@ def main():
             + " windows, X_0 solved for "
             f"{sess.b} nodes x {sess.m_aug} principals"
         )
-        for i, tr in enumerate(trackers):
-            tr.observe_step(
-                np.asarray(sess.x0[i]),
-                np.asarray(sess.init_busy_seconds[i]),
-                np.asarray(sess.init_invocations[i]),
-                sess.init_seconds,
-            )
+        trackers.observe_step(
+            np.asarray(sess.x0),
+            np.asarray(sess.init_busy_seconds),
+            np.asarray(sess.init_invocations),
+            sess.init_seconds,
+        )
 
     def on_tick(tick):
-        for i, tr in enumerate(trackers):
-            tr.observe_tick(tick.x[i], tick.busy_seconds[i], tick.a[i], 1.0)
+        trackers.observe_tick(tick.x, tick.busy_seconds, tick.a, 1.0, tick.valid)
         # Live capping view: attributed fleet power vs the software cap.
         fleet_watts = float(tick.tick_power.sum() + tick.unattributed.sum()) + idle_w * NODES
         cap.observe_power(fleet_watts)

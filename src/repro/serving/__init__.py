@@ -14,6 +14,7 @@ from repro.serving.control_plane import (
     ControlConfig,
     ControlLoop,
     EnergyFirstControlPlane,
+    FleetFootprintTracker,
     MeteredServer,
     ProfiledWorkload,
     StreamingFootprintTracker,
@@ -35,6 +36,7 @@ __all__ = [
     "ControlLoop",
     "EnergyAwareScheduler",
     "EnergyFirstControlPlane",
+    "FleetFootprintTracker",
     "Invocation",
     "KeepAliveCache",
     "MeteredServer",

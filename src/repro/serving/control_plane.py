@@ -8,9 +8,10 @@ footprints -> pricing/capping, in two execution substrates:
   benchmarks run through this — the profiler sees only degraded signals.
 - ``EnergyFirstControlPlane.profile_fleet``: the *streaming* fleet path —
   telemetry is fed window-by-window into a ``StreamingFleetSession``, each
-  engine tick updates every node's ``StreamingFootprintTracker`` live, and
-  the ``on_tick`` hook exposes conserved per-tick attribution for online
-  pricing/capping (docs/streaming.md, examples/stream_energy.py).
+  engine tick is folded live into one ``FleetFootprintTracker`` (per-node
+  ``StreamingFootprintTracker`` row views), and the ``on_tick`` hook
+  exposes conserved per-tick attribution for online pricing/capping
+  (docs/streaming.md, examples/stream_energy.py).
 - ``EnergyFirstControlPlane.run_capped``: discrete-event execution under a
   software power cap (paper Fig. 10): arrivals queue, the head of the queue
   is admitted iff ``W*t + J_lambda <= W_cap*t`` using live FaasMeter
@@ -63,9 +64,9 @@ import jax.numpy as jnp
 class ProfiledWorkload:
     """One node's profiling outcome: report + simulation + prices.
 
-    ``footprint_stream`` is the node's live-fed footprint tracker when the
-    workload went through the streaming fleet path (None on the per-node /
-    short-segment fallbacks).
+    ``footprint_stream`` is the node's row view of the live-fed fleet
+    footprint tracker when the workload went through the streaming fleet
+    path (None on the per-node / short-segment fallbacks).
     """
 
     report: FootprintReport
@@ -75,51 +76,91 @@ class ProfiledWorkload:
     footprint_stream: "StreamingFootprintTracker | None" = None
 
 
-class StreamingFootprintTracker:
-    """Streaming per-invocation footprint state for one node.
+def _per_invocation_indiv(j_indiv: np.ndarray, invocations: np.ndarray) -> np.ndarray:
+    """(..., M) running J/invocation of function execution alone."""
+    return np.where(invocations > 0, j_indiv / np.maximum(invocations, 1.0), 0.0)
+
+
+def _per_invocation_total(
+    j_indiv: np.ndarray, invocations: np.ndarray, idle_watts, elapsed_s
+) -> np.ndarray:
+    """(..., M) running J/invocation including the even idle-energy share
+    over each node's currently-active functions (§4.4 static-resource
+    policy); ``idle_watts`` and ``elapsed_s`` are (...,)."""
+    active = invocations > 0
+    n_active = np.maximum(active.sum(-1), 1)
+    idle_j = np.expand_dims(idle_watts * elapsed_s / n_active, -1)
+    total = j_indiv + np.where(active, idle_j, 0.0)
+    return np.where(active, total / np.maximum(invocations, 1.0), 0.0)
+
+
+class FleetFootprintTracker:
+    """Streaming per-invocation footprint state for a fleet of B nodes.
 
     The seed recomputed the whole footprint spectrum from scratch whenever a
     caller wanted fresh per-invocation numbers.  This tracker instead folds
     each observation — a whole Kalman step, or, on the live path, every
-    single telemetry tick — into running footprints in O(M), so the control
+    single telemetry tick — into running (B, M) footprints, so the control
     plane can serve per-invocation footprints (for pricing and capping
     admission) that are always current without any recomputation over
     history.  ``profile_fleet`` feeds it *live per tick* from the streaming
-    engine (``observe_tick``); ``observe_step`` remains for coarse feeds
-    (the init-segment seed, or replaying per-step trajectories).
+    engine (``observe_tick``, one masked update for the whole fleet);
+    ``observe_step`` remains for coarse feeds (the init-segment seed, or
+    replaying per-step trajectories).
+
+    It is also the sequence of its nodes' trackers: ``len``, ``tracker[i]``
+    and iteration give ``StreamingFootprintTracker`` row views.
     """
 
-    def __init__(self, num_fns: int, idle_watts: float = 0.0):
+    def __init__(self, num_fns: int, idle_watts):
         self.num_fns = num_fns
-        self.idle_watts = idle_watts
-        self.j_indiv = np.zeros(num_fns)        # cumulative attributed joules
-        self.invocations = np.zeros(num_fns)    # cumulative invocation counts
-        self.elapsed_s = 0.0
-        self.steps_seen = 0                     # observations folded in (any kind)
-        self.ticks_seen = 0                     # of which: live per-tick feeds
+        self.idle_watts = np.asarray(idle_watts, np.float64)      # (B,)
+        b = self.idle_watts.shape[0]
+        self.j_indiv = np.zeros((b, num_fns))       # cumulative attributed joules
+        self.invocations = np.zeros((b, num_fns))   # cumulative invocation counts
+        self.elapsed_s = np.zeros(b)
+        self.steps_seen = np.zeros(b, np.int64)     # observations folded in (any kind)
+        self.ticks_seen = np.zeros(b, np.int64)     # of which: live per-tick feeds
+        self._views = [StreamingFootprintTracker(self, i) for i in range(b)]
+
+    def __len__(self) -> int:
+        return len(self._views)
+
+    def __getitem__(self, i):
+        return self._views[i]
+
+    def __iter__(self):
+        return iter(self._views)
 
     def observe_step(
         self,
-        x_step: np.ndarray,       # (M,) per-function power estimate after the step
-        busy_seconds: np.ndarray,  # (M,) per-function runtime within the step
-        a_step: np.ndarray,       # (M,) invocations in the step
+        x_step: np.ndarray,        # (B, M+) per-function power estimate after the step
+        busy_seconds: np.ndarray,  # (B, M+) per-function runtime within the step
+        a_step: np.ndarray,        # (B, M+) invocations in the step
         step_seconds: float,
+        valid: np.ndarray | None = None,
     ) -> None:
         """Fold one Kalman step (or any coarse observation) into the state.
 
         Args:
-          x_step: (M+,) per-function power estimate for the interval (W);
-            entries past ``num_fns`` (shared principals) are ignored.
-          busy_seconds: (M+,) per-function runtime within the interval (s).
-          a_step: (M+,) invocations starting in the interval.
+          x_step: (B, M+) per-function power estimate for the interval (W);
+            columns past ``num_fns`` (shared principals) are ignored.
+          busy_seconds: (B, M+) per-function runtime within the interval (s).
+          a_step: (B, M+) invocations starting in the interval.
           step_seconds: interval length (s), for the idle-energy share.
+          valid: optional (B,) bool — only these rows are folded (a ragged
+            fleet's ended nodes stay frozen); None folds every row.
         """
-        self.j_indiv += np.asarray(busy_seconds[: self.num_fns], float) * np.asarray(
-            x_step[: self.num_fns], float
-        )
-        self.invocations += np.asarray(a_step[: self.num_fns], float)
-        self.elapsed_s += step_seconds
-        self.steps_seen += 1
+        m = self.num_fns
+        rows = True if valid is None else np.asarray(valid, bool)
+        cols = rows if valid is None else rows[:, None]
+        # float64 products and sums of the float32 rows (exact casts), so
+        # every live row is bitwise the fold of that node alone.
+        joules = np.multiply(busy_seconds[:, :m], x_step[:, :m], dtype=np.float64)
+        np.add(self.j_indiv, joules, out=self.j_indiv, where=cols)
+        np.add(self.invocations, a_step[:, :m], out=self.invocations, where=cols)
+        np.add(self.elapsed_s, step_seconds, out=self.elapsed_s, where=rows)
+        self.steps_seen += rows
 
     def observe_tick(
         self,
@@ -127,6 +168,7 @@ class StreamingFootprintTracker:
         busy_seconds: np.ndarray,
         a_tick: np.ndarray,
         tick_seconds: float,
+        valid: np.ndarray | None = None,
     ) -> None:
         """Fold one *live* engine tick (streaming path) into the state.
 
@@ -134,25 +176,82 @@ class StreamingFootprintTracker:
         estimate used is the causal one current at the tick, so footprints
         move the moment the streaming engine's estimate does.
         """
-        self.observe_step(x_tick, busy_seconds, a_tick, tick_seconds)
-        self.ticks_seen += 1
+        self.observe_step(x_tick, busy_seconds, a_tick, tick_seconds, valid)
+        self.ticks_seen += True if valid is None else np.asarray(valid, bool)
+
+    @property
+    def per_invocation_indiv(self) -> np.ndarray:
+        """(B, M) running J/invocation of function execution alone."""
+        return _per_invocation_indiv(self.j_indiv, self.invocations)
+
+    @property
+    def per_invocation_total(self) -> np.ndarray:
+        """(B, M) running J/invocation including the even idle-energy share
+        over each node's currently-active functions."""
+        return _per_invocation_total(
+            self.j_indiv, self.invocations, self.idle_watts, self.elapsed_s
+        )
+
+
+def _row(a: np.ndarray, i: int) -> np.ndarray:
+    """Read-only view of row ``i`` of ``a``."""
+    r = a[i]
+    r.flags.writeable = False
+    return r
+
+
+class StreamingFootprintTracker:
+    """One node's live footprints: a read-only view of row ``node`` of a
+    ``FleetFootprintTracker``, current as the fleet tracker folds ticks."""
+
+    __slots__ = ("fleet", "node")
+
+    def __init__(self, fleet: FleetFootprintTracker, node: int):
+        self.fleet = fleet
+        self.node = node
+
+    @property
+    def num_fns(self) -> int:
+        return self.fleet.num_fns
+
+    @property
+    def idle_watts(self) -> float:
+        return float(self.fleet.idle_watts[self.node])
+
+    @property
+    def j_indiv(self) -> np.ndarray:
+        """(M,) cumulative attributed joules."""
+        return _row(self.fleet.j_indiv, self.node)
+
+    @property
+    def invocations(self) -> np.ndarray:
+        """(M,) cumulative invocation counts."""
+        return _row(self.fleet.invocations, self.node)
+
+    @property
+    def elapsed_s(self) -> float:
+        return float(self.fleet.elapsed_s[self.node])
+
+    @property
+    def steps_seen(self) -> int:
+        return int(self.fleet.steps_seen[self.node])
+
+    @property
+    def ticks_seen(self) -> int:
+        return int(self.fleet.ticks_seen[self.node])
 
     @property
     def per_invocation_indiv(self) -> np.ndarray:
         """(M,) running J/invocation of function execution alone."""
-        return np.where(
-            self.invocations > 0, self.j_indiv / np.maximum(self.invocations, 1.0), 0.0
-        )
+        return _per_invocation_indiv(self.j_indiv, self.invocations)
 
     @property
     def per_invocation_total(self) -> np.ndarray:
-        """(M,) running J/invocation including the even idle-energy share
-        over currently-active functions (§4.4 static-resource policy)."""
-        active = self.invocations > 0
-        n_active = max(int(active.sum()), 1)
-        idle_j = self.idle_watts * self.elapsed_s / n_active
-        total = self.j_indiv + np.where(active, idle_j, 0.0)
-        return np.where(active, total / np.maximum(self.invocations, 1.0), 0.0)
+        """(M,) running J/invocation including the node's idle-energy share."""
+        f, i = self.fleet, self.node
+        return _per_invocation_total(
+            self.j_indiv, self.invocations, f.idle_watts[i], f.elapsed_s[i]
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,7 +339,7 @@ class ControlLoop:
         *,
         traces: list[InvocationTrace],
         registry: FunctionRegistry,
-        trackers: list,
+        trackers: FleetFootprintTracker,
         idle_watts,
         delta: float,
         init_n: int,
@@ -342,12 +441,13 @@ class ControlLoop:
         """Fleet-mean live per-invocation footprint J_lambda (J), or None
         before any node has metered an invocation of this function."""
         j = self.registry.index[fn_name]
-        vals = [
-            tr.per_invocation_indiv[j]
-            for tr in self.trackers
-            if tr is not None and tr.invocations[j] > 0
-        ]
-        return float(np.mean(vals)) if vals else None
+        fleet = self.trackers
+        seen = fleet.invocations[:, j] > 0
+        if not seen.any():
+            return None
+        return float(np.mean(
+            _per_invocation_indiv(fleet.j_indiv[seen, j], fleet.invocations[seen, j])
+        ))
 
     # -- the tick hook -------------------------------------------------------
 
@@ -648,10 +748,11 @@ class EnergyFirstControlPlane:
         One vectorized simulation pass generates every node's power traces;
         the telemetry is then replayed into a ``StreamingFleetSession`` one
         delta-window at a time, exactly as a live collection pipeline would
-        deliver it.  Each engine tick feeds every node's
-        ``StreamingFootprintTracker`` (``observe_tick``) — per-invocation
-        footprints are current *during* the segment, not reconstructed from
-        a finished one — and then calls ``on_tick(stream_tick, trackers)``,
+        deliver it.  Each engine tick is folded into the fleet's
+        ``FleetFootprintTracker`` in one masked update (``observe_tick``) —
+        per-invocation footprints are current *during* the segment, not
+        reconstructed from a finished one — and then calls
+        ``on_tick(stream_tick, trackers)``,
         the online pricing/capping hook (see examples/stream_energy.py).
 
         Falls back to the per-node path (no trackers) when the segment is
@@ -676,7 +777,9 @@ class EnergyFirstControlPlane:
             as data through the simulator and the engines.  ``None`` uses
             the simulator's own configuration for every node.
           on_tick: optional hook ``(core.profiler.StreamTick,
-            list[StreamingFootprintTracker]) -> None`` run per engine tick.
+            FleetFootprintTracker) -> None`` run per engine tick; the
+            fleet tracker is also the sequence of the nodes'
+            ``StreamingFootprintTracker`` row views.
           mesh: ``"auto"`` (default) builds a ``FleetMesh`` over the node
             axis when more than one device is visible and the fleet tiles
             onto them (``distributed.sharding.fleet_mesh_auto``), so a
@@ -725,7 +828,8 @@ class EnergyFirstControlPlane:
 
         Returns:
           One ``ProfiledWorkload`` per node, with ``footprint_stream``
-          holding the live-fed tracker (None on the short-segment fallback).
+          holding the node's row view of the live-fed fleet tracker (None
+          on the short-segment fallback).
         """
         if not traces:
             return []
@@ -811,13 +915,11 @@ class EnergyFirstControlPlane:
             )
             trackers: list[StreamingFootprintTracker | None] = [None] * len(traces)
         else:
-            trackers = [
-                StreamingFootprintTracker(num_fns, idle_watts=tel.idle_watts)
-                for tel in tels
-            ]
+            fleet = FleetFootprintTracker(num_fns, [tel.idle_watts for tel in tels])
+            trackers = list(fleet)
             if control is not None:
                 control.bind(
-                    traces=traces, registry=self.registry, trackers=trackers,
+                    traces=traces, registry=self.registry, trackers=fleet,
                     idle_watts=[tel.idle_watts for tel in tels],
                     delta=cfg.delta, init_n=plans[0][1],
                     n_used=plans[0][1] + s * cfg.step_windows,
@@ -837,36 +939,34 @@ class EnergyFirstControlPlane:
                     _x_cpu_cache["refits"] = n
                 return _x_cpu_cache["v"]
 
-            def _full_x(x_rest, i):
+            def _full_x(x_rest):
                 if not combined:
                     return x_rest
-                return np.asarray(x_rest[:num_fns]) + _x_cpu_now()[i]
+                return x_rest[:, :num_fns] + _x_cpu_now()
 
             def _on_bootstrap(sess):
                 # Seed with the init segment (X_0 estimate) so functions
                 # active only early still carry their energy.
-                for i, tr in enumerate(trackers):
-                    tr.observe_step(
-                        _full_x(np.asarray(sess.x0[i]), i),
-                        np.asarray(sess.init_busy_seconds[i]),
-                        np.asarray(sess.init_invocations[i]),
-                        sess.init_seconds,
-                    )
+                fleet.observe_step(
+                    _full_x(np.asarray(sess.x0)),
+                    np.asarray(sess.init_busy_seconds),
+                    np.asarray(sess.init_invocations),
+                    sess.init_seconds,
+                )
 
             def _on_tick(tk):
-                with tracing.span("faasmeter.control.trackers", tick=tk.t):
-                    for i, tr in enumerate(trackers):
-                        # Ragged fleet: a node whose stream has ended stops
-                        # accumulating (its engine state is frozen; folding the
-                        # dead ticks in would keep growing its idle share).
-                        if tk.valid is None or tk.valid[i]:
-                            tr.observe_tick(
-                                _full_x(tk.x[i], i), tk.busy_seconds[i], tk.a[i], cfg.delta
-                            )
+                # Ragged fleet: a node whose stream has ended stops
+                # accumulating (its engine state is frozen; folding the
+                # dead ticks in would keep growing its idle share).
+                nodes = len(fleet) if tk.valid is None else int(np.count_nonzero(tk.valid))
+                with tracing.span("faasmeter.control.trackers", tick=tk.t, nodes=nodes):
+                    fleet.observe_tick(
+                        _full_x(tk.x), tk.busy_seconds, tk.a, cfg.delta, tk.valid
+                    )
                 if control is not None:
-                    control.on_tick(tk, trackers)
+                    control.on_tick(tk, fleet)
                 if on_tick is not None:
-                    on_tick(tk, trackers)
+                    on_tick(tk, fleet)
 
             session = profiler.start_fleet_stream(
                 trace_arrays, num_fns=num_fns, duration=duration,

@@ -51,6 +51,7 @@ SERVING_EXPORTS = [
     "ControlLoop",
     "EnergyAwareScheduler",
     "EnergyFirstControlPlane",
+    "FleetFootprintTracker",
     "Invocation",
     "KeepAliveCache",
     "MeteredServer",

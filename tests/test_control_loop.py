@@ -165,6 +165,18 @@ class TestControlLoopSmall:
         )
 
 
+    def test_footprint_of_is_the_mean_over_node_views(self, run):
+        """The scheduler's J_lambda is the fleet mean of the live per-node
+        footprints, over the nodes that metered the function."""
+        reg, _, _, _, _, loop, _, _ = run
+        views = list(loop.trackers)
+        assert len(views) == loop.b
+        for j, name in enumerate(reg.names):
+            vals = [tr.per_invocation_indiv[j] for tr in views if tr.invocations[j] > 0]
+            want = float(np.mean(vals)) if vals else None
+            assert loop._footprint_of(name) == want
+
+
 class TestNoMigration:
     def test_per_node_counts_preserved_without_placement(self):
         reg, cp, traces, _, _, loop = _controlled_run(

@@ -7,6 +7,8 @@ and the recorded ``.xplane.pb`` is read back with ``ProfileData``.  Pinned:
 - ``faasmeter.session.dispatch``, ``faasmeter.session.emit`` and
   ``faasmeter.control.trackers`` occur once per emitted tick, with the
   ``tick`` values the ``on_tick`` hook saw;
+- ``faasmeter.control.trackers`` is the fleet tracker's one update: it
+  carries the rows it folded (``nodes``) and holds no span per node;
 - ``faasmeter.engine.fleet_step`` lies inside ``faasmeter.session.dispatch``;
 - one ``faasmeter.pull`` per device->host transfer the code path makes;
 - one ``faasmeter.put`` per host->device transfer, each on one device;
@@ -123,6 +125,19 @@ def test_one_span_per_emitted_tick(traced, name):
     ticks, lines = traced
     assert [tk.t for tk in ticks] == TICKS
     assert sorted(ev[3]["tick"] for ev in _named(lines, name)) == TICKS
+
+
+def test_tracker_span_is_one_fleet_update_per_tick(traced):
+    """Each emitted tick folds into the fleet tracker in one update: one
+    ``faasmeter.control.trackers`` span with ``tick`` and ``nodes`` (the
+    rows folded, both nodes here), and no span opened inside it per node."""
+    _, lines = traced
+    spans = _named(lines, "faasmeter.control.trackers")
+    assert sorted((ev[3]["tick"], ev[3]["nodes"]) for ev in spans) == [(t, 2) for t in TICKS]
+    for line in lines:
+        for _, s, e, _ in (ev for ev in line if ev[0] == "faasmeter.control.trackers"):
+            inside = [ev[0] for ev in line if s <= ev[1] and ev[2] <= e]
+            assert inside == ["faasmeter.control.trackers"], inside
 
 
 def test_fleet_step_lies_inside_dispatch(traced):
