@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure.
+"""Benchmark harness: one module per paper table/figure, plus the control loop.
 
     PYTHONPATH=src python -m benchmarks.run [--full | --smoke] [--only fig6,...]
 
@@ -29,33 +29,24 @@ MODULES = [
     ("fig9_pricing_variance", "Fig 9: pricing stability"),
     ("fig10_capping", "Fig 10: software power capping"),
     ("fig11_neighbors", "Fig 11: noisy neighbors"),
-    ("profiler_overhead", "Perf: fleet profiler throughput"),
-    ("streaming_overhead", "Perf: streaming engine per-tick overhead"),
-    ("sharded_fleet", "Perf: mesh-sharded fleet scaling"),
-    ("ragged_fleet", "Perf: ragged-fleet padding overhead vs rag ratio"),
-    ("combined_fleet", "Perf: combined-mode (§4.3) chip/rest split overhead"),
-    ("ingest_pipeline", "Perf: telemetry ingest — batched front-end + prefetch overlap"),
     ("control_loop", "Closed-loop control: cap overshoot, deferral cost, retrain recovery"),
-    ("hetero_fleet", "Serving: mixed-platform fleet — one batch, 1e-5 pin + zero-retrace gate"),
-    ("slot_serving", "Serving: slot-pool churn — ticks/sec + zero-retrace gate"),
-    ("kernel_bench", "Perf: kernel path"),
 ]
 
 # Engine hot paths whose jit caches are snapshotted around every module:
-# each smoke result carries a ``_jit_traces`` count (compiles the module
-# triggered on the serving/streaming paths), and the gate below turns the
-# tests' ad-hoc retrace guards into a fleet-wide CI invariant.
+# each result carries a ``_jit_traces`` count (compiles the module
+# triggered on the serving/streaming paths).  The zero-retrace guards are
+# tier-1 tests (test_streaming_engine, test_slot_serving, test_drain,
+# test_hetero_fleet, test_sharded_fleet).
 _TRACKED_JITS = (
-    ("repro.core.batched_engine", "fleet_step"),
-    ("repro.core.batched_engine", "fleet_stream_reset_slots"),
-    ("repro.core.batched_engine", "_bucket_init_solve"),
+    ("repro.core.engine", "fleet_step"),
+    ("repro.core.engine", "fleet_stream_reset_slots"),
+    ("repro.core.engine", "_bucket_init_solve"),
 )
 
 
 def _jit_cache_total() -> int | None:
     """Summed jit-cache size of the tracked engine entry points (None when
-    the private counter is unavailable — the gate then rides only the
-    modules' own ``retraces_after_warmup`` metrics)."""
+    the private counter is unavailable)."""
     total = 0
     try:
         for mod_name, fn_name in _TRACKED_JITS:
@@ -136,17 +127,6 @@ def main() -> None:
                 jit_after - jit_before
                 if jit_before is not None and jit_after is not None else -1
             )
-            # The fleet-wide retrace gate: any module that declares a
-            # post-warmup retrace count must report zero — an engine path
-            # that recompiles after its per-bucket warmup is a serving
-            # regression, not a slow benchmark.
-            retraces = metrics.get("retraces_after_warmup")
-            if args.smoke and retraces is not None and int(retraces) > 0:
-                raise ValueError(
-                    f"{mod_name} retraced after warmup "
-                    f"({retraces} extra jit traces) — the zero-retrace "
-                    "serving invariant is broken"
-                )
             results[mod_name] = metrics
             for k, v in metrics.items():
                 print(f"  {k:36s} {v:.6g}" if isinstance(v, float) else f"  {k:36s} {v}")

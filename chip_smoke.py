@@ -46,7 +46,7 @@ sys.path.insert(0, str(ROOT))
 
 NODES = 64
 DURATION_S = 600.0
-# benchmarks/sharded_fleet.py: the relative bound at benchmark scale.
+# The relative bound of the sharded-vs-unsharded pin at benchmark scale.
 REL_BOUND = 1e-4
 # tests/test_sharded_fleet.py: sharded vs unsharded.
 SHARD_BOUND = 1e-5

@@ -21,9 +21,15 @@ Layers (lower may never import higher):
     4  repro.serving.*                              control plane
 
 Equal-layer imports are allowed (peers compose); unmapped packages
-(telemetry, workload, data, models, ...) are infrastructure shared across
-layers and are not constrained by this contract.  Function-scope imports
+(telemetry, workload, models, ...) are infrastructure shared across
+layers and are not constrained by the layer order.  Function-scope imports
 count too: a lazy back-edge is still a back-edge.
+
+The LM fence: the model zoo, its configurations, the serve engine with its
+KV cache and the attention/rmsnorm kernels (``DEMO``) serve only the
+serving demo (examples/serve_energy.py).  No module of layers 0-4, and no
+module of ``telemetry`` or ``workload``, may reach them through any chain
+of ``repro`` imports, function-scope imports and parent packages included.
 
 Exit status 0 with an edge summary when clean; 1 with one line per
 violation otherwise.  Run from the repo root (CI does, via scripts/ci.sh).
@@ -60,6 +66,24 @@ LAYERS: dict[str, int] = {
     "repro.core": 3,  # package facade re-exports the profiler
     "repro.serving": 4,
 }
+
+
+DEMO = (
+    "repro.models",
+    "repro.configs",
+    "repro.serving.engine",
+    "repro.serving.kv_cache",
+    "repro.kernels.flash_attention",
+    "repro.kernels.decode_attention",
+    "repro.kernels.rmsnorm",
+    "repro.kernels.ops",
+)
+# Unlayered packages held to the fence as well.
+FENCED = ("repro.telemetry", "repro.workload")
+
+
+def _within(mod: str, prefixes) -> bool:
+    return any(mod == p or mod.startswith(p + ".") for p in prefixes)
 
 
 def _all_modules() -> set[str]:
@@ -117,6 +141,37 @@ def _edges(path: pathlib.Path, mod: str, known: set[str]):
                 yield node.lineno, sub if sub in known else target
 
 
+def _fence_violations(known: set[str]) -> list[str]:
+    """One line per fenced module from which an import chain reaches DEMO."""
+    graph: dict[str, set[str]] = {}
+    for path in SRC.rglob("*.py"):
+        mod = _module_name(path)
+        targets = {t for _, t in _edges(path, mod, known)}
+        # Importing a.b.c runs the packages a and a.b first.
+        for t in targets | {mod}:
+            parts = t.split(".")
+            targets |= {".".join(parts[:i]) for i in range(1, len(parts))}
+        graph[mod] = {t for t in targets if t != mod}
+    violations = []
+    for src in sorted(graph):
+        if _within(src, DEMO) or (_layer_of(src) is None and not _within(src, FENCED)):
+            continue
+        seen, frontier = {src: None}, [src]
+        while frontier:
+            mod = frontier.pop(0)
+            if _within(mod, DEMO):
+                chain = [mod]
+                while seen[chain[-1]] is not None:
+                    chain.append(seen[chain[-1]])
+                violations.append(f"fence: {' -> '.join(reversed(chain))}")
+                break
+            for nxt in sorted(graph.get(mod, ())):
+                if nxt not in seen:
+                    seen[nxt] = mod
+                    frontier.append(nxt)
+    return violations
+
+
 def main() -> int:
     known = _all_modules()
     files = sorted(p for p in SRC.rglob("*.py") if _layer_of(_module_name(p)) is not None)
@@ -135,14 +190,15 @@ def main() -> int:
                     f"back-edge {mod} (layer {src_layer}) -> "
                     f"{target} (layer {dst_layer})"
                 )
+    violations += _fence_violations(known)
     if violations:
-        print(f"layering check FAILED: {len(violations)} back-edge(s)")
+        print(f"layering check FAILED: {len(violations)} violation(s)")
         for v in violations:
             print(f"  {v}")
         return 1
     print(
         f"layering check OK ({len(files)} modules, {checked} in-contract "
-        "import edges, no back-edges)"
+        "import edges, no back-edges, nothing reaches the LM demo modules)"
     )
     return 0
 

@@ -1,20 +1,27 @@
 """repro: FaasMeter (Energy-First Serverless Computing) as a JAX/TPU framework.
 
-An energy-first serving + training control plane for TPU pods:
+A fleet energy controller: per-node telemetry in, per-function energy out,
+with caps, prices and retraining driven from those footprints.
 
 - ``repro.core``        -- the paper's contribution: statistical power
   disaggregation, Kalman-filtered online estimation, Shapley fair attribution,
   power capping, pricing, and the energy metrology framework (validation
-  metrics + marginal-energy ground truth).
+  metrics + marginal-energy ground truth); ``core.engine`` holds the jitted
+  fleet stages, ``core.sessions`` the live streaming sessions.
+- ``repro.serving``     -- the control plane (``profile_fleet``, the control
+  loop, the slot scheduler).
 - ``repro.telemetry``   -- power-source substrate (IPMI/plug/RAPL-like
   simulated sensors with matched noise/lag/quantization pathologies).
 - ``repro.workload``    -- Azure-trace-style FaaS workload generation.
-- ``repro.models``      -- the 10 assigned architectures (dense GQA, MoE,
-  Mamba2 hybrid, xLSTM, enc-dec, VLM) as scan-over-layers JAX models.
-- ``repro.training`` / ``repro.serving`` -- distributed train/serve runtimes.
-- ``repro.distributed`` -- mesh/sharding rules, checkpointing, collectives.
-- ``repro.kernels``     -- Pallas TPU kernels (flash attention, decode
-  attention, batched disaggregation solve) + jnp reference oracles.
+- ``repro.distributed`` -- ``FleetMesh`` (the fleet's node-axis sharding) and
+  the model zoo's logical-axis rules.
+- ``repro.kernels``     -- the Pallas ``disagg_gram`` kernel + jnp references.
+- ``repro.tracing``     -- the ``faasmeter.*`` host spans of the tick path.
+
+The serving demo (``examples/serve_energy.py``) alone uses ``repro.models``
+(reduced LM architectures), ``repro.configs``, ``serving.engine``,
+``serving.kv_cache`` and the attention/rmsnorm kernels;
+``scripts/check_layering.py`` fences them off from everything above.
 """
 
 from repro.version import __version__

@@ -133,15 +133,3 @@ class ArchConfig:
             per = d * 2 * di + di * d + 3 * di * di // max(self.num_heads, 1)
             n += self.num_layers * per
         return n
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: shared + top-k routed)."""
-        if self.family != "moe":
-            return self.param_count()
-        d = self.d_model
-        e_ff = self.expert_d_ff
-        attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
-        active_mlp = (self.num_shared_experts + self.top_k) * (3 * d * e_ff)
-        n = self.padded_vocab * d * 2
-        n += self.num_layers * (attn + active_mlp + d * self.num_experts + 2 * d)
-        return n

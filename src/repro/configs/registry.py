@@ -1,4 +1,4 @@
-"""Architecture registry: ``--arch <id>`` lookup for launchers and tests."""
+"""Architecture registry: ``get_config(<id>)`` lookup for the serving demo and tests."""
 
 from __future__ import annotations
 

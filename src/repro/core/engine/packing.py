@@ -20,8 +20,7 @@ def synthetic_fleet(
     b: int, s: int, n_w: int, m: int, *, seed: int = 0, density: float = 0.2
 ) -> FleetInputs:
     """Randomized synthetic fleet batch: sparse contributions, true power
-    plus noise.  Shared input generator for the equivalence tests and
-    ``benchmarks/kernel_bench.py`` so both exercise the same contract."""
+    plus noise.  Shared input generator of the equivalence tests."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
@@ -177,8 +176,8 @@ def synthetic_ragged_windows(
     given per-node ``lengths``, ready for ``pack_fleet_inputs``.  Windows
     past each node's length are filled with *non-zero junk* on purpose —
     the pad-and-mask contract says they must not be able to leak into any
-    result, and the ragged tests and ``benchmarks/ragged_fleet.py`` both
-    rely on that property being exercised, not vacuously true.
+    result, and the ragged tests rely on that property being exercised,
+    not vacuously true.
     """
     import numpy as np
 

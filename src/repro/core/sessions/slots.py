@@ -31,8 +31,7 @@ class SlotFleetSession(FleetSession):
 
     After ``warmup()`` (one dummy step + reset + every bucket solver) a
     churn trace of joins and leaves therefore runs with **zero retraces**
-    — pinned in tests/test_slot_serving.py and gated fleet-wide by the
-    smoke benchmark (``benchmarks/slot_serving.py``).
+    — pinned in tests/test_slot_serving.py (``test_churn_zero_retraces``).
 
     Mesh elasticity: the pool state may live sharded over a
     ``distributed.sharding.FleetMesh`` (``capacity`` must tile it), and

@@ -45,6 +45,7 @@ from repro.core.sessions.combined import (
     combined_chip_power,
 )
 from repro.core.sessions.drain import StreamTick, _DrainWorker
+from repro.core.sessions.prefetch import prefetch_iterator
 from repro.core.sessions.report import (
     FootprintReport,
     _node_durations,
@@ -397,7 +398,7 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         ``ticks`` is any iterator of objects with ``w_sys`` / ``w_chip`` /
         ``cp_frac`` / ``sys_frac`` attributes (``simulator.FleetTelemetryTick``
         in practice).  With ``prefetch >= 1`` the stream is pulled on a
-        background thread (``data.pipeline.prefetch_iterator``), so the
+        background thread (``sessions.prefetch.prefetch_iterator``), so the
         host-side sensing/resampling that produces tick ``t + 1`` overlaps
         the jitted ``fleet_step`` dispatched for tick ``t`` — the async
         ingest stage.  ``prefetch = 0`` falls back to strict alternation
@@ -417,8 +418,6 @@ class StreamingFleetSession(RetrainMixin, FleetSession):
         if self._drain is not None:
             raise ValueError("a drained ingest is already running on this session")
         if prefetch > 0:
-            from repro.data.pipeline import prefetch_iterator
-
             ticks = prefetch_iterator(ticks, size=prefetch)
         if drain:
             self._drain = _DrainWorker(self)

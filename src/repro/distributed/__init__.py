@@ -1,1 +1,1 @@
-"""Distributed runtime: sharding rules, meshes, checkpointing, collectives."""
+"""Sharding: ``FleetMesh`` for the fleet controller, logical-axis rules for the model zoo."""

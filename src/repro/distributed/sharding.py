@@ -38,7 +38,7 @@ Two built-in rule tables:
   with kv-heads on "model" when divisible (falls back to sequence).
 
 Models call :func:`shard_activation` at block boundaries; it is a no-op
-unless a rule context is active (set by the launchers via
+unless a rule context is active (set by the caller via
 :func:`use_rules`), keeping model code mesh-agnostic.
 """
 

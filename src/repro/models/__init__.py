@@ -1,5 +1,5 @@
 """Assigned-architecture model zoo (scan-over-layers JAX stacks)."""
 
-from repro.models.model_zoo import ModelApi, TensorSpec, build, model_flops
+from repro.models.model_zoo import ModelApi, TensorSpec, build
 
-__all__ = ["ModelApi", "TensorSpec", "build", "model_flops"]
+__all__ = ["ModelApi", "TensorSpec", "build"]

@@ -2,13 +2,8 @@
 rotary embeddings, and the LM loss.
 
 Parameters are declared as ``Param`` leaves (shape + logical axes + init
-law).  One structural walk yields, from the same declaration:
-
-- ``materialize(rng, tree)``      -> concrete fp32 arrays (for training),
-- ``abstract(tree, dtype)``       -> ShapeDtypeStructs (for the dry-run:
-  no allocation, exactly the shannon/kernels pattern),
-- ``logical_axes(tree)``          -> pytree of logical-axis tuples that
-  ``repro.distributed.sharding`` maps onto the mesh.
+law); ``materialize(tree, rng)`` instantiates them, and
+``repro.distributed.sharding`` maps the logical axes onto a mesh.
 
 Logical axis vocabulary (mapped to mesh axes by the sharding rules):
 
@@ -66,18 +61,6 @@ def materialize(tree: Any, rng: jax.Array, dtype=jnp.float32) -> Any:
     keys = jax.random.split(rng, len(leaves))
     vals = [_leaf_init(p, k, dtype) for p, k in zip(leaves, keys)]
     return jax.tree.unflatten(treedef, vals)
-
-
-def abstract(tree: Any, dtype=jnp.float32) -> Any:
-    """ShapeDtypeStruct twin of the parameter tree (dry-run, no allocation)."""
-    return jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, dtype), tree, is_leaf=is_param
-    )
-
-
-def logical_axes(tree: Any) -> Any:
-    """Pytree of logical-axis tuples, same structure as the params."""
-    return jax.tree.map(lambda p: p.axes, tree, is_leaf=is_param)
 
 
 def param_count(tree: Any) -> int:

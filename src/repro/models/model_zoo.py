@@ -2,18 +2,17 @@
 
 ``build(cfg)`` returns a :class:`ModelApi` with a family-independent surface:
 
-- ``params_def``                  declarative Param tree (materialize /
-                                  abstract / logical_axes all derive from it)
+- ``params_def``                  declarative Param tree (``materialize``
+                                  derives the parameters from it)
 - ``loss(params, batch)``         full train forward + masked CE (+ MoE aux)
 - ``prefill(params, batch)``      -> (last logits, decode cache)
 - ``decode(params, cache, token, pos)`` -> (logits, cache)   [serve_step]
-- ``train_inputs/prefill_inputs/decode_inputs(shape)``  TensorSpec trees for
-  the dry-run (ShapeDtypeStruct stand-ins, never allocated)
+- ``train_inputs/prefill_inputs/decode_inputs(shape)``  TensorSpec trees
+  (shape, dtype, logical axes) of each input
 - ``cache_spec(shape)``           TensorSpec tree matching the decode cache
 
-TensorSpec carries (shape, dtype, logical axes) so the launchers can derive
-NamedShardings for every input of every (arch x shape) cell from one code
-path.
+The serving demo (examples/serve_energy.py) and the tests build batches
+from these specs.
 """
 
 from __future__ import annotations
@@ -46,23 +45,10 @@ class TensorSpec:
     dtype: Any
     axes: tuple[str | None, ...]
 
-    def abstract(self) -> jax.ShapeDtypeStruct:
-        return jax.ShapeDtypeStruct(self.shape, self.dtype)
-
 
 def is_spec(x) -> bool:
     """True for ``TensorSpec`` leaves (tree-traversal predicate)."""
     return isinstance(x, TensorSpec)
-
-
-def spec_abstract(tree: Any) -> Any:
-    """Spec tree -> matching ``jax.ShapeDtypeStruct`` tree."""
-    return jax.tree.map(lambda s: s.abstract(), tree, is_leaf=is_spec)
-
-
-def spec_logical(tree: Any) -> Any:
-    """Spec tree -> logical sharding-axes tree."""
-    return jax.tree.map(lambda s: s.axes, tree, is_leaf=is_spec)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -389,20 +375,3 @@ def extend_cache(api: ModelApi, cache: dict, extra: int) -> dict:
         pad[axis] = (0, extra)
         out[name] = jnp.pad(x, pad)
     return out
-
-
-def model_flops(cfg: ArchConfig, shape: ShapeConfig) -> float:
-    """MODEL_FLOPS for the roofline's usefulness ratio.
-
-    train: 6*N*D (fwd+bwd); prefill: 2*N*D; decode: 2*N_active per token.
-    MoE uses active params.  D = tokens processed by the step.
-    """
-    n = cfg.active_param_count() if cfg.family == "moe" else cfg.param_count()
-    if shape.kind == "train":
-        d = shape.global_batch * shape.seq_len
-        return 6.0 * n * d
-    if shape.kind == "prefill":
-        d = shape.global_batch * shape.seq_len
-        return 2.0 * n * d
-    # decode: one token per sequence
-    return 2.0 * n * shape.global_batch

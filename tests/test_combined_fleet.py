@@ -48,6 +48,9 @@ from repro.core.profiler import (
 from repro.distributed.sharding import fleet_attribution_totals, fleet_mesh
 from repro.telemetry.counters import function_counters, window_counters
 
+#: Mesh sizes above one device: the ``multidevice`` params (tests/conftest.py).
+MULTI = [pytest.param(k, marks=pytest.mark.multidevice) for k in (2, 8)]
+
 #: sync_max_shift=0 pins the skew estimate to 0.0 on every path, so the
 #: combined pins are not polluted by the (documented, pure-mode-tested)
 #: init-vs-full-segment skew estimation difference of the streaming session.
@@ -445,7 +448,7 @@ def test_combined_streaming_matches_batched_bitwise_class():
 
 
 @pytest.mark.parametrize("ragged", [False, True], ids=["dense", "ragged"])
-@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("k", [1, *MULTI])
 def test_combined_sharded_matches_oracle(k, ragged):
     """fleet_profile_batched + the streaming session under a 1-, 2-, or
     8-device FleetMesh reproduce the per-node combined oracle — on dense
@@ -574,7 +577,7 @@ def test_session_rejects_missing_combined_inputs():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("k", [1, *MULTI])
 def test_fleet_totals_chip_split(k):
     if k > len(jax.devices()):
         pytest.skip(f"needs {k} devices")

@@ -190,3 +190,25 @@ def test_drain_rejects_reentrant_ingest():
     )
     reports = sess.finalize()
     assert len(reports) == len(arrays)
+
+
+def test_ingest_retraces_nothing_after_warmup():
+    """After one warm-up ingest, fresh sessions of the same fleet add no
+    ``fleet_step`` trace, whether they ingest inline, prefetched or
+    drained: the stream compiles once per fleet shape."""
+    from repro.core.engine import fleet_step
+
+    profiler, sim, traces, tels, arrays = _fixture()
+
+    def run(prefetch, drain):
+        sess = _open_session(profiler, arrays, tels, traces[0].num_fns, None)
+        sess.ingest(
+            sim.stream_fleet(traces, seeds=[11, 12]), prefetch=prefetch, drain=drain
+        )
+        sess.finalize()
+
+    run(0, False)
+    before = fleet_step._cache_size()
+    for prefetch, drain in ((0, False), (4, False), (4, True)):
+        run(prefetch, drain)
+    assert fleet_step._cache_size() == before

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core.batched_engine import pack_fleet_inputs, synthetic_ragged_windows
-from repro.data.pipeline import prefetch_iterator
+from repro.core.sessions.prefetch import prefetch_iterator
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,8 @@ PCFG = ProfilerConfig(
 )
 
 PLATFORMS = ["server", "desktop", "edge"]
+#: Mesh sizes above one device: the ``multidevice`` params (tests/conftest.py).
+MULTI = [pytest.param(k, marks=pytest.mark.multidevice) for k in (2, 8)]
 
 
 def _mixed_fixture(durations=None, platforms=PLATFORMS):
@@ -197,7 +199,28 @@ def test_mixed_fleet_matches_per_platform_batches(ragged):
         _assert_reports_close(streamed[i], refs[i], tag=f"stream:{plat}")
 
 
-@pytest.mark.parametrize("ndev", [1, 2, 8])
+def test_mixed_fleet_rerun_retraces_nothing():
+    """A mixed server/desktop/edge fleet profiled a second time through
+    ``profile_fleet`` adds no ``fleet_step`` trace: the platform mix is
+    data, not shapes."""
+    from repro.core.engine import fleet_step
+    from repro.serving.control_plane import EnergyFirstControlPlane
+
+    reg = paper_functions()
+    cp = EnergyFirstControlPlane(reg, SimulatorConfig(platform="server"), PCFG)
+    plats = [PLATFORMS[i % 3] for i in range(6)]
+    traces = [
+        generate_trace(reg, WorkloadConfig(duration_s=120.0, load=1.0, seed=20 + i))
+        for i in range(6)
+    ]
+    seeds = [50 + i for i in range(6)]
+    cp.profile_fleet(traces, seeds=seeds, platforms=plats, mode="combined")
+    before = fleet_step._cache_size()
+    cp.profile_fleet(traces, seeds=seeds, platforms=plats, mode="combined")
+    assert fleet_step._cache_size() == before
+
+
+@pytest.mark.parametrize("ndev", [1, *MULTI])
 def test_mixed_fleet_sharded_session(ndev):
     """The mixed-platform streaming session under a 1-/2-/8-device node
     mesh: stacked per-node parameters and the chip mask shard with the

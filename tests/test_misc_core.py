@@ -1,13 +1,10 @@
-"""Metrics, CPU model, baselines, pricing, data pipeline, workload gen, costs."""
-
-import dataclasses
+"""Metrics, CPU model, baselines, pricing, workload gen, compile cache."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import baselines, cpu_model, metrics, pricing
-from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.workload.azure import WorkloadConfig, generate_trace
 from repro.workload.functions import paper_functions
 from repro.workload.trace import concat_traces, pad_trace
@@ -98,31 +95,6 @@ class TestPricing:
         assert np.all(np.asarray(r["total_usd_per_inv"]) >= np.asarray(r["indiv_usd_per_inv"]))
 
 
-class TestDataPipeline:
-    def test_determinism_and_seek(self):
-        from repro.configs.registry import get_config
-        from repro.configs.shapes import ShapeConfig
-        from repro.models import build
-
-        api = build(get_config("internlm2-1.8b", reduced=True))
-        shape = ShapeConfig("t", 16, 2, "train")
-        b1 = synthetic_batch(api, shape, 5, DataConfig(seed=3))
-        b2 = synthetic_batch(api, shape, 5, DataConfig(seed=3))
-        b3 = synthetic_batch(api, shape, 6, DataConfig(seed=3))
-        np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-        assert not np.array_equal(b1["tokens"], b3["tokens"])
-
-    def test_labels_are_shifted_tokens(self):
-        from repro.configs.registry import get_config
-        from repro.configs.shapes import ShapeConfig
-        from repro.models import build
-
-        api = build(get_config("internlm2-1.8b", reduced=True))
-        b = synthetic_batch(api, ShapeConfig("t", 16, 2, "train"), 0)
-        np.testing.assert_array_equal(b["labels"][:, :-1], b["tokens"][:, 1:])
-        assert np.all(b["labels"][:, -1] == -1)
-
-
 class TestWorkload:
     def test_trace_bounds(self, registry):
         t = generate_trace(registry, WorkloadConfig(duration_s=120.0, seed=1))
@@ -154,51 +126,6 @@ class TestWorkload:
         p = pad_trace(a, 1024)
         assert p.fn_id.shape[0] % 1024 == 0
         assert p.num_invocations == a.num_invocations
-
-
-class TestCosts:
-    def test_dense_forward_close_to_2nd(self):
-        """Analytic forward ~ 2*N*D + attention for dense archs."""
-        from repro.configs.registry import get_config
-        from repro.configs.shapes import TRAIN_4K
-        from repro.launch.costs import forward_flops
-
-        cfg = get_config("granite-3-8b")
-        fwd = forward_flops(cfg, TRAIN_4K)["total"]
-        two_nd = 2.0 * cfg.param_count() * TRAIN_4K.global_batch * TRAIN_4K.seq_len
-        assert 0.9 < fwd / two_nd < 1.5, fwd / two_nd
-
-    def test_cost_model_vs_compiled_unrolled(self):
-        """Validate against XLA cost_analysis on a tiny LOOP-FREE model."""
-        import jax
-        import jax.numpy as jnp
-
-        d, f, s, b = 64, 256, 128, 4
-
-        def mlp_fwd(w1, w2, x):
-            return jnp.tanh(x @ w1) @ w2
-
-        lo = jax.jit(mlp_fwd).lower(
-            jax.ShapeDtypeStruct((d, f), jnp.float32),
-            jax.ShapeDtypeStruct((f, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, s, d), jnp.float32),
-        )
-        ca = lo.compile().cost_analysis()
-        if isinstance(ca, list):  # older jax returns [dict], newer a dict
-            ca = ca[0]
-        got = ca["flops"]
-        want = 2 * b * s * d * f * 2
-        assert 0.9 < got / want < 1.2, (got, want)
-
-    def test_step_cost_decode_memory_dominated(self):
-        from repro.configs.registry import get_config
-        from repro.configs.shapes import DECODE_32K
-        from repro.launch.costs import step_cost
-
-        c = step_cost(get_config("granite-3-8b"), DECODE_32K)
-        # decode arithmetic intensity << machine balance: bytes dominate
-        intensity = c.flops / c.hbm_bytes
-        assert intensity < 240  # v5e balance ~ 197e12/819e9 ~ 240
 
 
 class TestCompileCache:

@@ -50,6 +50,8 @@ from repro.distributed.sharding import (
 
 CFG = EngineConfig()
 ENGINES = [run_fleet, run_fleet_gram, run_fleet_stream]
+#: Mesh sizes above one device: the ``multidevice`` params (tests/conftest.py).
+MULTI = [pytest.param(k, marks=pytest.mark.multidevice) for k in (2, 8)]
 
 # Per-node window counts drawn from {T/2 .. T} (T = 5 steps of 8 ticks),
 # plus a node with zero full steps and one with a sub-step tail.
@@ -109,7 +111,7 @@ def test_ragged_engines_match_per_node_oracle(fn):
         )
 
 
-@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("k", [1, *MULTI])
 @pytest.mark.parametrize("fn", ENGINES, ids=lambda f: f.__name__)
 def test_ragged_sharded_matches_unsharded(fn, k):
     """The masked engines shard like the dense ones: the mask splits with

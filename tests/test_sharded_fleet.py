@@ -9,9 +9,9 @@ and 8-device meshes; fleet-level reductions go through a single ``psum``
 Also pinned: one jit trace for a whole sharded stream (the retrace guard),
 sharded state placement/donation, and the control plane's auto-mesh.
 
-Multi-device cases carry the ``multidevice`` marker and auto-skip unless
-run under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (CI's
-second job does exactly that).
+Multi-device cases carry the ``multidevice`` marker; on a one-device host
+they run in a child process that sees eight host devices
+(tests/conftest.py).
 """
 
 import jax
@@ -39,6 +39,8 @@ from repro.distributed.sharding import (
 
 ENGINES = [run_fleet, run_fleet_gram, run_fleet_stream]
 CFG = EngineConfig()
+#: Mesh sizes above one device: the ``multidevice`` params (tests/conftest.py).
+MULTI = [pytest.param(k, marks=pytest.mark.multidevice) for k in (2, 8)]
 
 
 def _mesh(k: int) -> FleetMesh:
@@ -188,7 +190,7 @@ def test_sharded_stream_conserves_per_tick():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("k", [1, *MULTI])
 def test_fleet_attribution_totals_psum_matches_sum(k):
     if k > len(jax.devices()):
         pytest.skip(f"needs {k} devices")

@@ -51,6 +51,8 @@ from repro.telemetry.simulator import churn_schedule
 
 CFG = EngineConfig()
 ENGINES = [run_fleet, run_fleet_gram, run_fleet_stream]
+#: Mesh sizes above one device: the ``multidevice`` params (tests/conftest.py).
+MULTI = [pytest.param(k, marks=pytest.mark.multidevice) for k in (2, 8)]
 
 
 def _tick_rows(ticks, t):
@@ -105,7 +107,7 @@ def test_static_pool_matches_engines(engine):
     assert pool.free_slots == 2  # spares stayed free and inert
 
 
-@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("k", [1, *MULTI])
 def test_static_pool_sharded(k, request):
     """Same pin with the pool state sharded over 1/2/8 fake devices."""
     if k > 1 and len(jax.devices()) < k:

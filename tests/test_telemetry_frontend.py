@@ -315,7 +315,7 @@ def test_stream_fleet_equals_simulate_fleet_ragged_bitwise():
 
 
 def test_prefetch_iterator_order_transfer_and_errors():
-    from repro.data.pipeline import prefetch_iterator
+    from repro.core.sessions.prefetch import prefetch_iterator
 
     assert list(prefetch_iterator(iter(range(50)), size=3)) == list(range(50))
     assert list(prefetch_iterator(iter([1, 2, 3]), size=2, transfer=lambda x: x * 10)) \
